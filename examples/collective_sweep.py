@@ -15,8 +15,8 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.compat import force_host_devices
-force_host_devices(8)   # before any jax import
+from repro.jax_setup import force_host_devices
+force_host_devices(8)   # CPU backend, 8 host devices; before any jax import
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import PicnicSimulator

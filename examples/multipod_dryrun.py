@@ -4,13 +4,13 @@ derived roofline terms — exactly what the full sweep does for all 40 cells.
 
   PYTHONPATH=src python examples/multipod_dryrun.py [arch] [shape]
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+
+from repro.jax_setup import force_host_devices
+force_host_devices(512)   # CPU backend, 512 host devices; before any jax import
 
 from repro.launch.dryrun import run_cell
 

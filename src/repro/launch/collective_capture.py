@@ -30,7 +30,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Sequence
 
-from repro.compat import force_host_devices
+from repro.jax_setup import force_host_devices
 from repro.core.interconnect import MeasuredTraffic
 
 # NOTE: importing this module never touches XLA_FLAGS / jax device state
@@ -62,14 +62,13 @@ def capture_cell(arch: str, *, mode: str = "decode", seq_len: int = 512,
     SP attention / partial-softmax decode paths; "pp" is the GPipe cell
     and needs a 3-factor mesh).  ``smoke`` uses the CPU-sized config.
     """
-    import jax
     from repro.configs import ShapeSpec, get_config, get_smoke_config
     from repro.launch import dryrun, hlo_cost
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
     sizes, axes = parse_mesh(mesh)
-    m = jax.make_mesh(sizes, axes)
+    m = make_mesh(sizes, axes)
     nchips = m.devices.size
     shape = ShapeSpec(f"{mode}_{seq_len}", seq_len, batch, mode)
 
@@ -78,7 +77,7 @@ def capture_cell(arch: str, *, mode: str = "decode", seq_len: int = 512,
     compiled = fn.lower(*args).compile()
     t_compile = time.time() - t0
     parsed = hlo_cost.analyze(compiled.as_text(), nchips)
-    xla = compat.cost_analysis(compiled)
+    xla = compiled.cost_analysis()
 
     wire_per_chip = parsed.wire_bytes
     return {
@@ -123,10 +122,13 @@ def capture_in_subprocess(arch: str, *, modes: Sequence[str] = ("prefill",
     """Run the capture CLI in a fresh process (the forced host device count
     must be set before JAX initializes, which an already-running process —
     e.g. ``benchmarks/run.py`` — cannot do for itself).  ``devices``
-    defaults to exactly what the mesh spec needs."""
+    defaults to exactly what the mesh spec needs.  The child is a CPU
+    study: it is pinned to the CPU backend, so on an accelerator host it
+    never reaches for a chip that this process may hold."""
     if devices is None:
         devices = math.prod(parse_mesh(mesh)[0])
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     # inherit the user's XLA flags; only the device-count flag is ours
     inherited = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                       env.get("XLA_FLAGS", "")).strip()

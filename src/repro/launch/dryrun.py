@@ -1,5 +1,5 @@
-from repro.compat import force_host_devices
-force_host_devices(512)   # appended to any pre-set XLA_FLAGS
+from repro.jax_setup import force_host_devices, use_compile_cache
+force_host_devices(512)   # CPU backend; appended to any pre-set XLA_FLAGS
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -25,7 +25,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
-from repro import compat, models
+from repro import models
 from repro.configs import SHAPES, get_config, ASSIGNED_ARCHS
 from repro.launch.mesh import make_production_mesh
 from repro.launch import hlo_cost
@@ -174,7 +174,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)   # list-vs-dict normalized
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         # trip-count-aware accounting (xla cost_analysis counts while
         # bodies once — see hlo_cost.py + EXPERIMENTS.md §Dry-run)
@@ -241,7 +241,7 @@ def main():
     ap.add_argument("--variant", default="baseline")
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
+    use_compile_cache()
 
     meshes = ["pod1", "pod2"] if args.mesh == "both" else [args.mesh]
     if args.all:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import models
-from repro.sharding.shmap import shard_map
 from repro.models.model import FwdCtx, _scan_groups, group_layout
 from repro.models.common import apply_norm
 from repro.launch.steps import cross_entropy
@@ -147,7 +146,7 @@ def pp_forward(cfg, params, tokens, *, mesh, stage_axis: str = "pod",
     kw = {}
     if partial_manual:
         kw["axis_names"] = frozenset({stage_axis})
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, tok_spec),
         out_specs=(P(), P()),

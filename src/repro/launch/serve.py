@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
@@ -27,7 +28,7 @@ import numpy as np
 
 from repro import models
 from repro.configs import get_config, get_smoke_config
-from repro.data import ByteTokenizer
+from repro.jax_setup import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_serve_step
 from repro.sharding import ShardingCtx, use_sharding
@@ -48,21 +49,36 @@ class Server:
         self.cfg = cfg
         self.max_batch = max_batch
         self.max_len = max_len
-        self.params = models.init_params(cfg, jax.random.PRNGKey(seed))
         mesh = make_host_mesh()
         rules = sp.activation_rules(cfg, mesh, "decode")
         self.ctx = ShardingCtx(mesh, rules)
+        # params and cache are created on the mesh: left unplaced they sit
+        # on device 0 and every step copies them to the other devices
+        init_params = functools.partial(models.init_params, cfg)
+        pspecs = sp.param_specs(
+            cfg, jax.eval_shape(init_params, jax.random.PRNGKey(seed)),
+            mesh, "decode")
+        self.params = jax.jit(init_params,
+                              out_shardings=sp.to_named(pspecs, mesh))(
+            jax.random.PRNGKey(seed))
+        init_cache = functools.partial(models.init_cache, cfg, max_batch,
+                                       max_len)
+        cache_sharding = sp.to_named(
+            sp.cache_specs(cfg, jax.eval_shape(init_cache), mesh), mesh)
+        self.cache = jax.jit(init_cache, out_shardings=cache_sharding)()
+        tokens = jnp.zeros((max_batch, 1), jnp.int32)
+        token_sharding = sp.to_named(sp.batch_specs(cfg, tokens, mesh), mesh)
+        self.tokens = jax.device_put(tokens, token_sharding)
         serve_step = make_serve_step(cfg)
 
         def wrapped(params, cache, tok, cache_len):
             with use_sharding(self.ctx):
                 return serve_step(params, cache, tok, cache_len)
 
-        self.step_fn = jax.jit(wrapped, donate_argnums=(1,))
-        self.cache = models.init_cache(cfg, max_batch, max_len)
+        self.step_fn = jax.jit(wrapped, donate_argnums=(1,),
+                               out_shardings=(token_sharding, cache_sharding))
         self.slots = [Slot() for _ in range(max_batch)]
         self.cur_len = 0          # shared cache length (continuous batch)
-        self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
 
     def admit(self, request_id: int, prompt: np.ndarray) -> bool:
         """Prefill a prompt into a free slot (per-slot prefill via the
@@ -102,6 +118,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=256)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     srv = Server(cfg, max_batch=args.n_requests, max_len=args.max_len)
@@ -114,9 +131,11 @@ def main(argv=None):
         srv.decode_round()
     dt = time.time() - t0
     total_tokens = sum(len(s.generated) for s in srv.slots)
+    dev = jax.devices()[0]
     print(f"served {args.n_requests} requests, {total_tokens} tokens "
           f"in {dt:.2f}s ({total_tokens/dt:.1f} tok/s on "
-          f"{len(jax.devices())} CPU device(s))")
+          f"{len(jax.devices())} {dev.platform} device(s), "
+          f"{dev.device_kind})")
     for s in srv.slots:
         assert len(s.generated) == args.max_new
         assert all(0 <= t < cfg.vocab_size for t in s.generated)

@@ -26,6 +26,7 @@ from repro import models
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs import get_config, get_smoke_config
 from repro.data import PackedStream
+from repro.jax_setup import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import init_train_state, make_train_step
 from repro.runtime import (RestartPolicy, StragglerDetector, WorkerFailure)
@@ -49,6 +50,7 @@ def main(argv=None):
                     help="inject N worker failures to exercise restart")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 from .common import apply_rope, dense_init, dtype_of
 from repro.sharding import ctx as shctx
 from repro.sharding.ctx import shard_hint
-from repro.sharding.shmap import shard_map
 
 NEG_INF = -1e30
 
@@ -219,8 +218,8 @@ def sp_flash_attention(q, k, v, *, mesh, dp_axes, seq_axes=("model",),
                                kv_chunk=kv_chunk)
 
     spec = P(bspec, seq_axes, None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _axes_size(mesh, axes):
@@ -285,7 +284,7 @@ def picnic_decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len, *,
         out = o / jnp.maximum(l[..., None], 1e-30)
         return out[:, None].astype(ql.dtype), kl, vl
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(qspec, qspec, qspec, cspec, cspec),
         out_specs=(qspec, cspec, cspec), check_vma=False)(
         q, k_new, v_new, k_cache, v_cache)

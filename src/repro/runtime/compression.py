@@ -73,7 +73,7 @@ def compressed_psum(grads, error_state, axis_name: str):
 
 
 def compressed_allreduce(grads, error_state, mesh, axis_name: str):
-    """:func:`compressed_psum` wrapped in a (version-portable) shard_map.
+    """:func:`compressed_psum` wrapped in a shard_map.
 
     ``grads``/``error_state``: pytrees whose leaves are sharded on their
     leading dim over ``axis_name``.  Returns (reduced grads, new error
@@ -81,13 +81,11 @@ def compressed_allreduce(grads, error_state, mesh, axis_name: str):
     DP hillclimb and the distributed tests drive; inside a larger
     shard_map call :func:`compressed_psum` directly.
     """
-    from repro.sharding.shmap import shard_map
-
     spec = jax.sharding.PartitionSpec(axis_name)
 
     def body(g, e):
         return compressed_psum(g, e, axis_name)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                   out_specs=(spec, spec), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), check_vma=False)
     return fn(grads, error_state)

@@ -1,5 +1,3 @@
 from .ctx import ShardingCtx, shard_hint, use_sharding, current
-from .shmap import shard_map
 
-__all__ = ["ShardingCtx", "shard_hint", "use_sharding", "current",
-           "shard_map"]
+__all__ = ["ShardingCtx", "shard_hint", "use_sharding", "current"]

@@ -4,6 +4,8 @@ conversion and the simulator's measured-vs-analytic C2C flag.
 Fast lane: no lowering here (the real capture is exercised by the slow
 HLO tests and `benchmarks/run.py distributed`); these tests pin the
 contract between capture records, MeasuredTraffic, and the simulator."""
+import os
+
 import pytest
 
 from repro.configs import get_smoke_config
@@ -50,6 +52,32 @@ def test_subprocess_device_count_follows_mesh(monkeypatch):
     cc.capture_in_subprocess("x", mesh="1x8")
     assert seen["flags"] == ("--xla_dump_to=/tmp/d "
                              "--xla_force_host_platform_device_count=8")
+
+
+@pytest.mark.parametrize("inherited", [None, "tpu", "tpu,cpu"])
+def test_subprocess_is_pinned_to_cpu(monkeypatch, inherited):
+    # the child is a CPU compile study: on an accelerator host it must not
+    # reach for a chip the parent may hold
+    seen = {}
+
+    def fake_run(cmd, **kw):
+        seen["platforms"] = kw["env"].get("JAX_PLATFORMS")
+
+        class R:
+            returncode = 0
+            stdout = "[]"
+            stderr = ""
+        return R()
+
+    monkeypatch.setattr(cc.subprocess, "run", fake_run)
+    monkeypatch.setenv("JAX_PLATFORMS", "sentinel")
+    if inherited is None:
+        monkeypatch.delenv("JAX_PLATFORMS")
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", inherited)
+    cc.capture_in_subprocess("x", mesh="1x8")
+    assert seen["platforms"] == "cpu"
+    assert os.environ.get("JAX_PLATFORMS") == inherited   # parent untouched
 
 
 def test_importing_capture_module_leaves_device_state_alone():

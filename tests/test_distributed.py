@@ -28,6 +28,7 @@ def run_sub(body: str):
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
         assert len(jax.devices()) == 8
     """).format(src=SRC) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -47,7 +48,7 @@ def test_picnic_decode_matches_baseline():
     from repro.sharding import specs as sp
 
     cfg = dataclasses.replace(get_smoke_config("yi-34b"), dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     params = models.init_params(cfg, jax.random.PRNGKey(0))
     B, S = 4, 32
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
@@ -74,7 +75,7 @@ def test_picnic_decode_matches_baseline():
 def test_sp_attention_matches_flash():
     run_sub("""
     from repro.models.attention import flash_attention, sp_flash_attention
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (2, 64, 4, 16))
     k = jax.random.normal(ks[1], (2, 64, 2, 16))
@@ -107,7 +108,7 @@ def test_sharded_train_step_matches_single_device():
 
     cfg = dataclasses.replace(get_smoke_config("smollm-360m"),
                               dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     params, opt = init_train_state(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
                               cfg.vocab_size)
@@ -137,7 +138,7 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_psum_matches_exact():
     run_sub("""
     from repro.runtime import compressed_allreduce
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 128)) * 1e-3
 
     out, _ = jax.jit(lambda g, e: compressed_allreduce(
@@ -164,7 +165,7 @@ def test_pipeline_parallel_matches_reference():
 
     cfg = dataclasses.replace(get_smoke_config("smollm-360m"),
                               dtype="float32", n_layers=4, remat=False)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     params = models.init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                               cfg.vocab_size)

@@ -21,6 +21,7 @@ def run_sub(body: str):
         sys.path.insert(0, {src!r})
         import jax
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
     """).format(src=SRC) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600)
@@ -31,7 +32,6 @@ def run_sub(body: str):
 @pytest.mark.slow
 def test_scan_flops_counted_with_trip_count():
     run_sub("""
-    from repro import compat
     from repro.launch import hlo_cost
 
     def f(ws, x):
@@ -44,8 +44,7 @@ def test_scan_flops_counted_with_trip_count():
     x = jax.ShapeDtypeStruct((64, 512), jnp.bfloat16)
     c = jax.jit(f).lower(ws, x).compile()
     # the raw xla number undercounts by the trip count...
-    # (compat normalizes the list-vs-dict cost_analysis return)
-    raw = compat.cost_analysis(c)["flops"]
+    raw = c.cost_analysis()["flops"]
     analytic = 10 * 2 * 64 * 512 * 512
     assert raw < 0.2 * analytic
     # ...the parser does not
@@ -59,7 +58,7 @@ def test_collectives_and_tp_flops_exact():
     run_sub("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch import hlo_cost
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
 
     def g(w, x):
         return x @ w

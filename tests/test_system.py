@@ -43,6 +43,28 @@ def test_serving_continuous_batching():
     assert all(len(s.generated) == 6 for s in srv.slots)
 
 
+def test_server_places_params_and_cache_on_its_mesh():
+    from repro.launch.serve import Server
+    from repro.configs import get_smoke_config
+    srv = Server(get_smoke_config("smollm-360m"), max_batch=2, max_len=32)
+    for tree in (srv.params, srv.cache, srv.tokens):
+        for x in jax.tree.leaves(tree):
+            assert x.sharding.mesh == srv.ctx.mesh
+
+
+def test_serve_main_names_the_device(capsys, monkeypatch, tmp_path):
+    from repro.launch.serve import main
+    # set, the variable leaves JAX's cache alone (JAX read it at import)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    main(["--arch", "smollm-360m", "--smoke", "--n-requests", "2",
+          "--max-new", "3", "--max-len", "32"])
+    out = capsys.readouterr().out
+    dev = jax.devices()[0]
+    assert f"{dev.platform} device(s), {dev.device_kind}" in out
+    assert "CPU device(s)" not in out
+    assert out.strip().endswith("OK")
+
+
 def test_benchmark_harness_runs():
     """Every paper-table benchmark executes and emits its derived value."""
     import benchmarks.run as br
