@@ -10,6 +10,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro import models
 from repro.configs import get_smoke_config
@@ -31,21 +32,18 @@ def _cpu_env(**extra):
     return env
 
 
-def test_exits_nonzero_without_tpu(tmp_path):
-    r = subprocess.run([sys.executable, str(SCRIPT)], capture_output=True,
+@pytest.mark.parametrize("where", ["in_repo", "alone_in_a_directory"])
+def test_exits_nonzero_without_tpu(tmp_path, where):
+    script = SCRIPT
+    if where == "alone_in_a_directory":
+        script = tmp_path / "chip_smoke.py"
+        script.write_text(SCRIPT.read_text())
+    r = subprocess.run([sys.executable, str(script)], capture_output=True,
                        text=True, timeout=300, cwd=tmp_path, env=_cpu_env())
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
-    assert "no TPU" in r.stderr
-
-
-def test_exits_nonzero_alone_in_a_directory(tmp_path):
-    lone = tmp_path / "chip_smoke.py"
-    lone.write_text(SCRIPT.read_text())
-    r = subprocess.run([sys.executable, str(lone)], capture_output=True,
-                       text=True, timeout=300, cwd=tmp_path, env=_cpu_env())
-    assert r.returncode != 0
-    assert '"ok"' not in r.stdout
+    if where == "in_repo":
+        assert "no TPU" in r.stderr
 
 
 def test_serve_phase_fills_every_slot():
@@ -79,12 +77,14 @@ def test_four_chip_phases_on_four_host_devices():
         spec.loader.exec_module(cs)
         devs = jax.devices()
         assert len(devs) == 4
-        errs = cs.four_chip_errors(get_smoke_config(cs.ARCH), devs,
-                                   max_len=64)
-        assert set(errs) == {{"server_host_mesh", "picnic_decode_1x4"}}
-        for name, err in errs.items():
-            assert err <= cs.LOGITS_TOL, (name, err)
-        print("ERRS", errs)
+        cfg = get_smoke_config(cs.ARCH)
+        a = cs.server_step_errors(cfg, devs, max_len=64)
+        b = cs.picnic_errors(cfg, devs, max_len=64)
+        assert set(a) == {{"kv_rel_l2", "token_deficit"}}
+        assert set(b) == {{"logits_rel_l2", "kv_rel_l2", "one_chip_vs_f32",
+                          "picnic_vs_f32"}}
+        assert cs.within_tolerance(a) and cs.within_tolerance(b), (a, b)
+        print("ERRS", a, b)
     """)
     env = _cpu_env(XLA_FLAGS="--xla_force_host_platform_device_count=4",
                    PYTHONPATH=str(ROOT / "src"))
