@@ -86,7 +86,8 @@ def make_prefill_step(cfg, *, kv_max: int):
             prefix_embeds=batch.get("prefix_embeds"),
             encoder_embeds=batch.get("encoder_embeds"),
             collect_cache=True, kv_max=kv_max)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         return next_tok, cache
     return prefill_step
 
@@ -97,7 +98,8 @@ def make_serve_step(cfg):
     def serve_step(params, cache, token, cache_len):
         logits, cache = models.decode_step(cfg, params, token, cache,
                                            cache_len)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         return next_tok, cache
     return serve_step
 

@@ -363,19 +363,21 @@ def attn_sublayer(cfg, p, x, *, positions, causal=True, impl="flash",
         S = q.shape[1]
         n_seq = _axes_size(ctx.mesh, seq_axes)
         if S % n_seq == 0 and n_seq > 1:
-            out = sp_flash_attention(
-                q, k, v, mesh=ctx.mesh,
-                dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
-                seq_axes=seq_axes, causal=causal, window=window,
-                prefix_len=prefix_len)
+            with jax.named_scope("attend"):
+                out = sp_flash_attention(
+                    q, k, v, mesh=ctx.mesh,
+                    dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
+                    seq_axes=seq_axes, causal=causal, window=window,
+                    prefix_len=prefix_len)
             B, S = x.shape[:2]
             out = out.reshape(B, S, cfg.q_dim)
             return out @ p["wo"], (k, v)
     q = shard_hint(q, "act_heads")
     k = shard_hint(k, "act_kv_heads")
     fn = flash_attention if impl == "flash" else full_attention
-    out = fn(q, k, v, causal=causal, window=window, kv_len=kv_len,
-             prefix_len=prefix_len)
+    with jax.named_scope("attend"):
+        out = fn(q, k, v, causal=causal, window=window, kv_len=kv_len,
+                 prefix_len=prefix_len)
     B, S = x.shape[:2]
     out = out.reshape(B, S, cfg.q_dim)
     return out @ p["wo"], (k, v)
@@ -397,20 +399,23 @@ def attn_decode_sublayer(cfg, p, x, cache_k, cache_v, cache_len, *,
         seq_axes = tuple(ctx.opt("seq_axes", ("model",)))
         n_seq = _axes_size(ctx.mesh, seq_axes)
         if cache_k.shape[1] % n_seq == 0 and n_seq > 1:
-            out, cache_k, cache_v = picnic_decode_attention(
-                q, k, v, cache_k, cache_v, cache_len, mesh=ctx.mesh,
-                dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
-                seq_axes=seq_axes, window=window)
+            with jax.named_scope("attend"):
+                out, cache_k, cache_v = picnic_decode_attention(
+                    q, k, v, cache_k, cache_v, cache_len, mesh=ctx.mesh,
+                    dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
+                    seq_axes=seq_axes, window=window)
             out = out.reshape(B, 1, cfg.q_dim)
             return out @ p["wo"], cache_k, cache_v
     # baseline (GSPMD) path: append then attend
     idx = cache_len - 1
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k.astype(cache_k.dtype), (0, idx, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v.astype(cache_v.dtype), (0, idx, 0, 0))
-    cache_k = shard_hint(cache_k, "kv_cache")
-    cache_v = shard_hint(cache_v, "kv_cache")
-    out = decode_attention(q, cache_k, cache_v, cache_len, window=window)
+    with jax.named_scope("kv_write"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k.astype(cache_k.dtype), (0, idx, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v.astype(cache_v.dtype), (0, idx, 0, 0))
+        cache_k = shard_hint(cache_k, "kv_cache")
+        cache_v = shard_hint(cache_v, "kv_cache")
+    with jax.named_scope("attend"):
+        out = decode_attention(q, cache_k, cache_v, cache_len, window=window)
     out = out.reshape(B, 1, cfg.q_dim)
     return out @ p["wo"], cache_k, cache_v

@@ -128,44 +128,51 @@ def _block_forward(cfg, kind, p, x, ctx: FwdCtx):
     cache = {}
     aux = jnp.zeros((), jnp.float32)
     if kind in ("dense", "moe", "enc", "dec", "shared_attn"):
-        h = apply_norm(cfg, p.get("ln1"), x)
-        attn_out, (k, v) = A.attn_sublayer(
-            cfg, p["attn"], h, positions=ctx.positions,
-            causal=ctx.causal and kind != "enc",
-            impl=ctx.impl, window=cfg.sliding_window,
-            prefix_len=ctx.prefix_len)
-        x = x + attn_out
-        if ctx.collect_cache:
-            cache = {"k": _alloc_cache(k, ctx.kv_max),
-                     "v": _alloc_cache(v, ctx.kv_max)}
+        with jax.named_scope("attention"):
+            h = apply_norm(cfg, p.get("ln1"), x)
+            attn_out, (k, v) = A.attn_sublayer(
+                cfg, p["attn"], h, positions=ctx.positions,
+                causal=ctx.causal and kind != "enc",
+                impl=ctx.impl, window=cfg.sliding_window,
+                prefix_len=ctx.prefix_len)
+            x = x + attn_out
+            if ctx.collect_cache:
+                with jax.named_scope("kv_write"):
+                    cache = {"k": _alloc_cache(k, ctx.kv_max),
+                             "v": _alloc_cache(v, ctx.kv_max)}
         if kind == "dec":
-            h = apply_norm(cfg, p["lnx"], x)
-            enc = ctx.encoder_out
-            q, _, _ = A.qkv_project(cfg, p["cross"], h)
-            ek = (enc @ p["cross"]["wk"]).reshape(
-                enc.shape[0], enc.shape[1], cfg.n_kv_heads, cfg.head_dim)
-            ev = (enc @ p["cross"]["wv"]).reshape(
-                enc.shape[0], enc.shape[1], cfg.n_kv_heads, cfg.head_dim)
-            co = A.full_attention(q, ek, ev, causal=False)
-            x = x + co.reshape(*h.shape[:2], cfg.q_dim) @ p["cross"]["wo"]
+            with jax.named_scope("attention"):
+                h = apply_norm(cfg, p["lnx"], x)
+                enc = ctx.encoder_out
+                q, _, _ = A.qkv_project(cfg, p["cross"], h)
+                ek = (enc @ p["cross"]["wk"]).reshape(
+                    enc.shape[0], enc.shape[1], cfg.n_kv_heads, cfg.head_dim)
+                ev = (enc @ p["cross"]["wv"]).reshape(
+                    enc.shape[0], enc.shape[1], cfg.n_kv_heads, cfg.head_dim)
+                with jax.named_scope("attend"):
+                    co = A.full_attention(q, ek, ev, causal=False)
+                x = x + co.reshape(*h.shape[:2], cfg.q_dim) @ p["cross"]["wo"]
             if ctx.collect_cache:
                 cache["cross_k"], cache["cross_v"] = ek, ev
-        h = apply_norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, aux = X.moe_sublayer(cfg, p["moe"], h)
-        else:
-            y = M.mlp_sublayer(cfg, p["mlp"], h)
-        x = x + y
+        with jax.named_scope("moe" if kind == "moe" else "mlp"):
+            h = apply_norm(cfg, p["ln2"], x)
+            if kind == "moe":
+                y, aux = X.moe_sublayer(cfg, p["moe"], h)
+            else:
+                y = M.mlp_sublayer(cfg, p["mlp"], h)
+            x = x + y
         return x, cache, aux
     if kind == "mamba":
-        h = apply_norm(cfg, p["ln1"], x)
-        if ctx.collect_cache:
-            y, (conv_s, ssm_s) = S.mamba_sublayer(cfg, p["mamba"], h,
-                                                  return_state=True)
-            cache = {"conv": conv_s, "ssm": ssm_s}
-        else:
-            y = S.mamba_sublayer(cfg, p["mamba"], h)
-        return x + y, cache, aux
+        with jax.named_scope("mamba"):
+            h = apply_norm(cfg, p["ln1"], x)
+            if ctx.collect_cache:
+                y, (conv_s, ssm_s) = S.mamba_sublayer(cfg, p["mamba"], h,
+                                                      return_state=True)
+                cache = {"conv": conv_s, "ssm": ssm_s}
+            else:
+                y = S.mamba_sublayer(cfg, p["mamba"], h)
+            x = x + y
+        return x, cache, aux
     raise ValueError(kind)
 
 
@@ -199,7 +206,8 @@ def _scan_groups(cfg, params, x, ctx: FwdCtx, remat: bool):
         return x, (caches, aux)
 
     body = jax.checkpoint(group_body) if remat else group_body
-    x, (caches, auxs) = jax.lax.scan(body, x, params["layers"])
+    with jax.named_scope("layers"):
+        x, (caches, auxs) = jax.lax.scan(body, x, params["layers"])
     return x, caches, jnp.sum(auxs)
 
 
@@ -212,7 +220,8 @@ def forward(cfg, params, tokens, *, prefix_embeds=None, encoder_embeds=None,
     Returns (logits, aux, cache|None).
     """
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     prefix_len = 0
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
@@ -243,10 +252,11 @@ def forward(cfg, params, tokens, *, prefix_embeds=None, encoder_embeds=None,
                  prefix_len=prefix_len, encoder_out=encoder_out,
                  collect_cache=collect_cache, kv_max=max(kv_max, S))
     x, caches, aux = _scan_groups(cfg, params, x, ctx, cfg.remat)
-    x = apply_norm(cfg, params["final_norm"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    logits = shard_hint(logits, "logits")
+    with jax.named_scope("head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = x @ head
+        logits = shard_hint(logits, "logits")
     if prefix_len:
         logits = logits[:, prefix_len:]
     return logits, aux, (caches if collect_cache else None)
@@ -286,29 +296,37 @@ def init_cache(cfg, batch: int, max_len: int):
 
 def _block_decode(cfg, kind, p, x, c, cache_len):
     if kind in ("dense", "moe", "dec", "shared_attn"):
-        h = apply_norm(cfg, p.get("ln1"), x)
-        attn_out, ck, cv = A.attn_decode_sublayer(
-            cfg, p["attn"], h, c["k"], c["v"], cache_len,
-            window=cfg.sliding_window)
-        x = x + attn_out
+        with jax.named_scope("attention"):
+            h = apply_norm(cfg, p.get("ln1"), x)
+            attn_out, ck, cv = A.attn_decode_sublayer(
+                cfg, p["attn"], h, c["k"], c["v"], cache_len,
+                window=cfg.sliding_window)
+            x = x + attn_out
         newc = {"k": ck, "v": cv}
         if kind == "dec":
-            h = apply_norm(cfg, p["lnx"], x)
-            q, _, _ = A.qkv_project(cfg, p["cross"], h)
-            co = A.full_attention(q, c["cross_k"], c["cross_v"], causal=False)
-            x = x + co.reshape(x.shape[0], 1, cfg.q_dim) @ p["cross"]["wo"]
+            with jax.named_scope("attention"):
+                h = apply_norm(cfg, p["lnx"], x)
+                q, _, _ = A.qkv_project(cfg, p["cross"], h)
+                with jax.named_scope("attend"):
+                    co = A.full_attention(q, c["cross_k"], c["cross_v"],
+                                          causal=False)
+                x = x + co.reshape(x.shape[0], 1, cfg.q_dim) @ p["cross"]["wo"]
             newc["cross_k"], newc["cross_v"] = c["cross_k"], c["cross_v"]
-        h = apply_norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, _ = X.moe_sublayer(cfg, p["moe"], h)
-        else:
-            y = M.mlp_sublayer(cfg, p["mlp"], h)
-        return x + y, newc
+        with jax.named_scope("moe" if kind == "moe" else "mlp"):
+            h = apply_norm(cfg, p["ln2"], x)
+            if kind == "moe":
+                y, _ = X.moe_sublayer(cfg, p["moe"], h)
+            else:
+                y = M.mlp_sublayer(cfg, p["mlp"], h)
+            x = x + y
+        return x, newc
     if kind == "mamba":
-        h = apply_norm(cfg, p["ln1"], x)
-        y, conv_s, ssm_s = S.mamba_decode_sublayer(cfg, p["mamba"], h,
-                                                   c["conv"], c["ssm"])
-        return x + y, {"conv": conv_s, "ssm": ssm_s}
+        with jax.named_scope("mamba"):
+            h = apply_norm(cfg, p["ln1"], x)
+            y, conv_s, ssm_s = S.mamba_decode_sublayer(cfg, p["mamba"], h,
+                                                       c["conv"], c["ssm"])
+            x = x + y
+        return x, {"conv": conv_s, "ssm": ssm_s}
     raise ValueError(kind)
 
 
@@ -316,7 +334,8 @@ def decode_step(cfg, params, token, cache, cache_len):
     """token: (B, 1) int32; cache_len: scalar (tokens valid AFTER this step).
     Returns (logits (B,1,V), new_cache)."""
     kinds, _ = group_layout(cfg)
-    x = jnp.take(params["embed"], token, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], token, axis=0)
     if cfg.is_encoder_decoder:  # whisper: absolute sinusoidal positions
         from .common import sinusoidal_at
         x = x + sinusoidal_at(cache_len - 1, cfg.d_model).astype(x.dtype)[None, None]
@@ -336,8 +355,10 @@ def decode_step(cfg, params, token, cache, cache_len):
             newc[key] = nc
         return x, newc
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-    x = apply_norm(cfg, params["final_norm"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
+    with jax.named_scope("layers"):
+        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+    with jax.named_scope("head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = x @ head
     return logits, new_cache
