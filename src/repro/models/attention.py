@@ -232,13 +232,14 @@ def _axes_size(mesh, axes):
 def picnic_decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len, *,
                             mesh, dp_axes, seq_axes=("model",), window=None):
     """PICNIC distributed-scratchpad decode: the KV cache stays sequence-
-    sharded; the new token's K/V is appended by the OWNING shard only (the
-    paper's cyclic scratchpad write), each shard computes local partial
-    flash-softmax terms, and the combine is a psum over the seq axes — the
-    in-network reduction of paper §III.  Wire traffic per step is
-    O(B*H*D) instead of O(cache).
+    sharded; each shard computes local partial flash-softmax terms over its
+    cached positions, the shard that owns position ``cache_len - 1`` (the
+    paper's cyclic scratchpad slot) adds the new token's own term, and the
+    combine is a psum over the seq axes — the in-network reduction of paper
+    §III.  Wire traffic per step is O(B*H*D) instead of O(cache).  The cache
+    is only read: the caller writes the new K/V at ``cache_len - 1``.
 
-    Returns (out (B,1,Hq,D), new_k_cache, new_v_cache)."""
+    Returns out (B,1,Hq,D)."""
     B, _, Hq, D = q.shape
     S = k_cache.shape[1]
     n_seq = _axes_size(mesh, seq_axes)
@@ -254,26 +255,14 @@ def picnic_decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len, *,
             idx = idx + jax.lax.axis_index(a) * mult
             mult *= mesh.shape[a]
         base = idx * S_local
-        # --- local append (only the owning shard's write survives) -------
         gpos = cache_len - 1
-        li = jnp.clip(gpos - base, 0, S_local - 1)
         owns = (gpos >= base) & (gpos < base + S_local)
-
-        def append(buf, new):
-            cur = jax.lax.dynamic_slice(
-                buf, (0, li, 0, 0), (buf.shape[0], 1) + buf.shape[2:])
-            upd = jnp.where(owns, new.astype(buf.dtype), cur)
-            return jax.lax.dynamic_update_slice(buf, upd, (0, li, 0, 0))
-
-        kl = append(kl, knl)
-        vl = append(vl, vnl)
-        # --- local partial attention -------------------------------------
-        kpos = base + jnp.arange(S_local)
-        valid = kpos[None, :] < cache_len
-        if window is not None:
-            valid &= kpos[None, :] >= cache_len - window
-        valid = jnp.broadcast_to(valid, (ql.shape[0], S_local))
-        o, m, l = decode_attention_partial(ql[:, 0], kl, vl, valid)
+        # --- local partial attention, the new token on its owning shard ---
+        valid = _cached_valid(base + jnp.arange(S_local), cache_len, window,
+                              ql.shape[0])
+        o, m, l = merge_partials(
+            decode_attention_partial(ql[:, 0], kl, vl, valid),
+            token_partial(ql[:, 0], knl, vnl, owns))
         # --- in-network reduction (hierarchical over the seq axes) -------
         for a in seq_axes:
             M = jax.lax.pmax(m, a)
@@ -282,12 +271,11 @@ def picnic_decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len, *,
             l = jax.lax.psum(l * scale, a)
             m = M
         out = o / jnp.maximum(l[..., None], 1e-30)
-        return out[:, None].astype(ql.dtype), kl, vl
+        return out[:, None].astype(ql.dtype)
 
     return jax.shard_map(
         body, mesh=mesh, in_specs=(qspec, qspec, qspec, cspec, cspec),
-        out_specs=(qspec, cspec, cspec), check_vma=False)(
-        q, k_new, v_new, k_cache, v_cache)
+        out_specs=qspec, check_vma=False)(q, k_new, v_new, k_cache, v_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -318,29 +306,56 @@ def decode_attention_partial(q, k, v, valid):
     return o, m, l
 
 
-def combine_partials(o, m, l, axis_name: str):
-    """psum/pmax combine of partial softmax terms over a mesh axis."""
-    M = jax.lax.pmax(m, axis_name)
-    scale = jnp.exp(m - M)
-    num = jax.lax.psum(o * scale[..., None], axis_name)
-    den = jax.lax.psum(l * scale, axis_name)
-    return num / jnp.maximum(den[..., None], 1e-30)
+def token_partial(q, k_new, v_new, valid=True):
+    """The new token's own partial-softmax term, in the form of
+    ``decode_attention_partial``: one key, so l = 1 and o = its value.
+
+    q: (B, Hq, D); k_new, v_new: (B, 1, Hkv, D); ``valid`` False leaves the
+    term out (m = NEG_INF)."""
+    B, Hq, D = q.shape
+    Hkv = k_new.shape[2]
+    G = Hq // Hkv
+    qb = q.reshape(B, Hkv, G, D)
+    m = jnp.einsum("bhgd,bhd->bhg", qb, k_new[:, 0],
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    m = jnp.where(valid, m, NEG_INF)
+    o = jnp.broadcast_to(v_new[:, 0, :, None].astype(jnp.float32),
+                         (B, Hkv, G, D))
+    return o, m, jnp.ones_like(m)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *, window=None):
-    """q: (B, 1, Hq, D) vs cache (B, S, Hkv, D); positions >= cache_len masked.
+def merge_partials(a, b):
+    """Two partial-softmax terms (o, m, l) as one, over both key sets."""
+    (o1, m1, l1), (o2, m2, l2) = a, b
+    m = jnp.maximum(m1, m2)
+    s1, s2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
+    return o1 * s1[..., None] + o2 * s2[..., None], m, l1 * s1 + l2 * s2
+
+
+def _cached_valid(kpos, cache_len, window, B):
+    """(B, S) mask of the cached positions a decode step attends besides
+    its own token at ``cache_len - 1``: those before it, within the
+    window."""
+    valid = kpos[None, :] < cache_len - 1
+    if window is not None:
+        valid = valid & (kpos[None, :] >= cache_len - window)
+    return jnp.broadcast_to(valid, (B, kpos.shape[0]))
+
+
+def decode_attention(q, k_cache, v_cache, k_new, v_new, cache_len, *,
+                     window=None):
+    """q: (B, 1, Hq, D) vs the cache (B, S, Hkv, D) before this step, whose
+    positions >= cache_len - 1 are masked, plus the new token's own K/V
+    (B, 1, Hkv, D) at position cache_len - 1, in one float32 softmax.
 
     Pure jnp: under jit+GSPMD a seq-sharded cache turns the reduction into
     ICI collectives automatically (baseline path).
     """
     B, _, Hq, D = q.shape
-    S = k_cache.shape[1]
-    kpos = jnp.arange(S)
-    valid = kpos[None, :] < cache_len                          # (1 or B, S)
-    if window is not None:
-        valid = valid & (kpos[None, :] >= cache_len - window)
-    valid = jnp.broadcast_to(valid, (B, S))
-    o, m, l = decode_attention_partial(q[:, 0], k_cache, v_cache, valid)
+    valid = _cached_valid(jnp.arange(k_cache.shape[1]), cache_len, window, B)
+    o, m, l = merge_partials(
+        decode_attention_partial(q[:, 0], k_cache, v_cache, valid),
+        token_partial(q[:, 0], k_new, v_new))
     out = o / jnp.maximum(l[..., None], 1e-30)
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
@@ -385,37 +400,39 @@ def attn_sublayer(cfg, p, x, *, positions, causal=True, impl="flash",
 
 def attn_decode_sublayer(cfg, p, x, cache_k, cache_v, cache_len, *,
                          window=None):
-    """One-token decode: x (B, 1, d). Cache is written at cache_len - 1
-    (the caller appends the new K/V before calling) — here we take the
-    already-updated cache."""
+    """One-token decode: x (B, 1, d) at position cache_len - 1.  The cache
+    is only read; the new token's K/V are attended as the cache will hold
+    them (rounded to its dtype) and returned for the caller to write at
+    cache_len - 1.  Returns (out (B, 1, d), k_new, v_new (B, 1, Hkv, D))."""
     q, k, v = qkv_project(cfg, p, x)
     pos = jnp.asarray(cache_len - 1)[None]
     if cfg.use_rope:
         q = apply_rope(q, pos[None, :], cfg.rope_theta)
         k = apply_rope(k, pos[None, :], cfg.rope_theta)
+    k = k.astype(cache_k.dtype)
+    v = v.astype(cache_v.dtype)
     B = x.shape[0]
-    ctx = shctx.current()
-    if ctx is not None and ctx.opt("picnic_decode"):
-        seq_axes = tuple(ctx.opt("seq_axes", ("model",)))
-        n_seq = _axes_size(ctx.mesh, seq_axes)
-        if cache_k.shape[1] % n_seq == 0 and n_seq > 1:
-            with jax.named_scope("attend"):
-                out, cache_k, cache_v = picnic_decode_attention(
-                    q, k, v, cache_k, cache_v, cache_len, mesh=ctx.mesh,
-                    dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
-                    seq_axes=seq_axes, window=window)
-            out = out.reshape(B, 1, cfg.q_dim)
-            return out @ p["wo"], cache_k, cache_v
-    # baseline (GSPMD) path: append then attend
-    idx = cache_len - 1
-    with jax.named_scope("kv_write"):
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, idx, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, idx, 0, 0))
-        cache_k = shard_hint(cache_k, "kv_cache")
-        cache_v = shard_hint(cache_v, "kv_cache")
+    seq_axes = _picnic_seq_axes(cache_k.shape[1])
     with jax.named_scope("attend"):
-        out = decode_attention(q, cache_k, cache_v, cache_len, window=window)
+        if seq_axes:
+            ctx = shctx.current()
+            out = picnic_decode_attention(
+                q, k, v, cache_k, cache_v, cache_len, mesh=ctx.mesh,
+                dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
+                seq_axes=seq_axes, window=window)
+        else:
+            out = decode_attention(q, cache_k, cache_v, k, v, cache_len,
+                                   window=window)
     out = out.reshape(B, 1, cfg.q_dim)
-    return out @ p["wo"], cache_k, cache_v
+    return out @ p["wo"], k, v
+
+
+def _picnic_seq_axes(S):
+    """The mesh axes a ``picnic_decode`` context shards an S-long cache
+    over, or None where it is off or they do not divide S."""
+    ctx = shctx.current()
+    if ctx is None or not ctx.opt("picnic_decode"):
+        return None
+    seq_axes = tuple(ctx.opt("seq_axes", ("model",)))
+    n_seq = _axes_size(ctx.mesh, seq_axes)
+    return seq_axes if n_seq > 1 and S % n_seq == 0 else None

@@ -295,14 +295,16 @@ def init_cache(cfg, batch: int, max_len: int):
 
 
 def _block_decode(cfg, kind, p, x, c, cache_len):
+    """One block's decode over its cache entry ``c``, which it only reads.
+    Returns (x, new): an attention block's new token K/V (B, 1, Hkv, D),
+    to be written at cache_len - 1, or a Mamba block's new state."""
     if kind in ("dense", "moe", "dec", "shared_attn"):
         with jax.named_scope("attention"):
             h = apply_norm(cfg, p.get("ln1"), x)
-            attn_out, ck, cv = A.attn_decode_sublayer(
+            attn_out, k, v = A.attn_decode_sublayer(
                 cfg, p["attn"], h, c["k"], c["v"], cache_len,
                 window=cfg.sliding_window)
             x = x + attn_out
-        newc = {"k": ck, "v": cv}
         if kind == "dec":
             with jax.named_scope("attention"):
                 h = apply_norm(cfg, p["lnx"], x)
@@ -311,7 +313,6 @@ def _block_decode(cfg, kind, p, x, c, cache_len):
                     co = A.full_attention(q, c["cross_k"], c["cross_v"],
                                           causal=False)
                 x = x + co.reshape(x.shape[0], 1, cfg.q_dim) @ p["cross"]["wo"]
-            newc["cross_k"], newc["cross_v"] = c["cross_k"], c["cross_v"]
         with jax.named_scope("moe" if kind == "moe" else "mlp"):
             h = apply_norm(cfg, p["ln2"], x)
             if kind == "moe":
@@ -319,7 +320,7 @@ def _block_decode(cfg, kind, p, x, c, cache_len):
             else:
                 y = M.mlp_sublayer(cfg, p["mlp"], h)
             x = x + y
-        return x, newc
+        return x, {"k": k, "v": v}
     if kind == "mamba":
         with jax.named_scope("mamba"):
             h = apply_norm(cfg, p["ln1"], x)
@@ -330,9 +331,29 @@ def _block_decode(cfg, kind, p, x, c, cache_len):
     raise ValueError(kind)
 
 
+def _write_token(c, new, cache_len):
+    """A block's cache entry after the step: an attention block's stacked
+    K/V (groups, B, max_len, Hkv, D) with the new token's (groups, B, 1, Hkv,
+    D) written at cache_len - 1, in place where the cache is donated (its
+    other leaves, whisper's cross K/V, as they were); a Mamba block's new
+    state."""
+    if "k" not in c:
+        return new
+    idx = cache_len - 1
+    out = dict(c)
+    for name in ("k", "v"):
+        out[name] = shard_hint(jax.lax.dynamic_update_slice(
+            c[name], new[name], (0, 0, idx, 0, 0)), "kv_cache")
+    return out
+
+
 def decode_step(cfg, params, token, cache, cache_len):
     """token: (B, 1) int32; cache_len: scalar (tokens valid AFTER this step).
-    Returns (logits (B,1,V), new_cache)."""
+    Returns (logits (B,1,V), new_cache).
+
+    The layer scan only reads the stacked cache and yields each attention
+    block's new K/V; one write per stack after the scan places them, so the
+    stack is not copied through the scan."""
     kinds, _ = group_layout(cfg)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], token, axis=0)
@@ -343,22 +364,25 @@ def decode_step(cfg, params, token, cache, cache_len):
 
     def body(x, xs):
         gp, gc = xs
-        newc = {}
+        new = {}
         for i, kind in enumerate(kinds):
             if kind == "shared_attn":
                 key = f"b{i}_shared"
-                x, nc = _block_decode(cfg, "shared_attn", shared, x, gc[key],
-                                      cache_len)
+                x, new[key] = _block_decode(cfg, "shared_attn", shared, x,
+                                            gc[key], cache_len)
             else:
                 key = f"b{i}_{kind}"
-                x, nc = _block_decode(cfg, kind, gp[key], x, gc[key], cache_len)
-            newc[key] = nc
-        return x, newc
+                x, new[key] = _block_decode(cfg, kind, gp[key], x, gc[key],
+                                            cache_len)
+        return x, new
 
     with jax.named_scope("layers"):
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        x, new = jax.lax.scan(body, x, (params["layers"], cache))
+        with jax.named_scope("attention"), jax.named_scope("kv_write"):
+            cache = {key: _write_token(c, new[key], cache_len)
+                     for key, c in cache.items()}
     with jax.named_scope("head"):
         x = apply_norm(cfg, params["final_norm"], x)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = x @ head
-    return logits, new_cache
+    return logits, cache

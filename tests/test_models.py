@@ -78,6 +78,43 @@ def test_decode_matches_forward(arch):
     assert rel < 1e-3, f"{arch}: rel {rel}"
 
 
+@pytest.mark.parametrize("arch,window", [
+    ("olmo-1b", None), ("llama4-maverick-400b-a17b", None),
+    ("zamba2-2.7b", None), ("whisper-large-v3", None), ("olmo-1b", 8),
+], ids=["dense", "moe", "hybrid", "audio", "dense-sliding-window"])
+def test_decode_writes_the_token_kv_where_prefill_would(arch, window):
+    """prefill(S-1) + decode(1) leaves at position S-1 of every layer's
+    K/V what prefill(S) writes there, zeros from S on, and whisper's cross
+    K/V as they were."""
+    cfg = dataclasses.replace(get_smoke_config(arch), sliding_window=window)
+    if cfg.moe:
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    params = models.init_params(cfg, KEY)
+    B, S, kv_max = 2, 24, 32
+    toks, kw = _inputs(cfg, B, S)
+    _, _, want = models.forward(cfg, params, toks, collect_cache=True,
+                                kv_max=kv_max, **kw)
+    _, _, cache = models.forward(cfg, params, toks[:, :S - 1],
+                                 collect_cache=True, kv_max=kv_max, **kw)
+    _, got = jax.jit(models.decode_step, static_argnums=0)(
+        cfg, params, toks[:, S - 1:S], cache, jnp.int32(S))
+    attn = [key for key, c in got.items() if "k" in c]
+    assert attn
+    for key in attn:
+        for name in ("k", "v"):
+            new = got[key][name][:, :, S - 1].astype(jnp.float32)
+            ref = want[key][name][:, :, S - 1].astype(jnp.float32)
+            rel = float(jnp.max(jnp.abs(new - ref)) / jnp.max(jnp.abs(ref)))
+            assert rel < 2e-2, (key, name, rel)
+            assert (got[key][name][:, :, :S - 1]
+                    == cache[key][name][:, :, :S - 1]).all(), (key, name)
+            assert not got[key][name][:, :, S:].any(), (key, name)
+        for name in ("cross_k", "cross_v"):
+            if name in cache[key]:
+                assert (got[key][name] == cache[key][name]).all(), (key, name)
+
+
 def test_multi_token_greedy_decode_stable():
     """8 decode steps produce valid tokens and a growing cache."""
     cfg = get_smoke_config("smollm-360m")

@@ -66,6 +66,22 @@ def test_llama_serve_step_compiles_for_v5e(one_chip, llama_params):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_olmo_serve_step_writes_the_donated_cache_in_place(one_chip):
+    """At the benchmark's decode batch the step makes no copy of the stacked
+    K/V: its temporaries are a sliver of the cache, and the whole donated
+    cache is aliased to the cache it returns."""
+    cfg = get_config("olmo-1b")
+    params = _on(one_chip, ispec.params_shapes(cfg))
+    token, cache, cache_len = _on(one_chip, ispec.decode_arg_specs(
+        cfg, ShapeSpec("decode_256", 256, 192, "decode")))
+    mem = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, token, cache_len).compile().memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert mem.temp_size_in_bytes < cache_bytes / 100
+    assert mem.alias_size_in_bytes == cache_bytes
+
+
 def test_llama_prefill_step_compiles_for_v5e(one_chip, llama_params):
     cfg = get_config("llama3.2-1b")
     batch = ispec.prefill_batch_specs(cfg, ShapeSpec("prefill_512", 512, 4,
