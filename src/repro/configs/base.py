@@ -49,6 +49,7 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None        # default: d_model // n_heads
     norm: str = "rmsnorm"                 # rmsnorm | layernorm | nonparam_ln
+    norm_eps: Optional[float] = None      # default: 1e-6 for rmsnorm, else 1e-5
     mlp: str = "swiglu"                   # swiglu | geglu | gelu
     rope_theta: float = 10000.0
     use_rope: bool = True
@@ -79,6 +80,8 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
+        if self.norm_eps is None:
+            object.__setattr__(self, "norm_eps", 1e-6 if self.norm == "rmsnorm" else 1e-5)
 
     @property
     def q_dim(self) -> int:

@@ -1,7 +1,8 @@
 """Mistral-Nemo-12B [hf:mistralai/Mistral-Nemo-Base-2407].
 
 40L d_model=5120 32H (GQA kv=8) d_ff=14336 vocab=131072, head_dim=128,
-128k context (rope_theta=1e6).
+128k context (rope_theta=1e6), RMSNorm eps 1e-5 (the source's
+``rms_norm_eps``).  Every value is from memory of the source's config.json.
 """
 from .base import ModelConfig
 
@@ -16,5 +17,6 @@ CONFIG = ModelConfig(
     d_ff=14336,
     vocab_size=131072,
     rope_theta=1_000_000.0,
+    norm_eps=1e-5,
     max_seq=131072,
 )

@@ -34,7 +34,7 @@ def rmsnorm(x: jax.Array, scale: jax.Array | None, eps: float = 1e-6) -> jax.Arr
 
 
 def layernorm(x: jax.Array, scale: jax.Array | None, bias: jax.Array | None,
-              eps: float = 1e-5) -> jax.Array:
+              eps: float) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)
     mu = jnp.mean(x, axis=-1, keepdims=True)
@@ -49,11 +49,12 @@ def layernorm(x: jax.Array, scale: jax.Array | None, bias: jax.Array | None,
 
 def apply_norm(cfg, p: Params | None, x: jax.Array) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        return rmsnorm(x, p["scale"] if p else None)
+        return rmsnorm(x, p["scale"] if p else None, cfg.norm_eps)
     if cfg.norm == "layernorm":
-        return layernorm(x, p["scale"] if p else None, p["bias"] if p else None)
+        return layernorm(x, p["scale"] if p else None, p["bias"] if p else None,
+                         cfg.norm_eps)
     if cfg.norm == "nonparam_ln":  # OLMo: LN without learned affine
-        return layernorm(x, None, None)
+        return layernorm(x, None, None, cfg.norm_eps)
     raise ValueError(cfg.norm)
 
 
