@@ -3,8 +3,9 @@
 Design notes (PICNIC adaptation, see DESIGN.md §3):
   * train/prefill use a blockwise online-softmax ("flash") implementation --
     ``lax.scan`` over KV chunks nested in a scan over Q chunks, so the S x S
-    score matrix is never materialized.  This mirrors the paper's
-    FlashAttention two-level nested loop on the IPCN mesh.
+    score matrix is never materialized, and a block that the causal, window
+    or length mask hides entirely is skipped, in the gradient too.  This
+    mirrors the paper's FlashAttention two-level nested loop on the IPCN mesh.
   * decode computes q against the full KV cache.  When the cache is
     sequence-sharded over the ``model`` mesh axis (the PICNIC
     distributed-scratchpad scheme) the softmax reduction becomes an
@@ -26,6 +27,11 @@ from repro.sharding import ctx as shctx
 from repro.sharding.ctx import shard_hint
 
 NEG_INF = -1e30
+# Default q and kv chunk of the jnp flash path.  On a TPU v5e, causal
+# attention at 24 x 1920 tokens x 16 heads of 128 took 18.1 ms with 256-wide
+# blocks (36 of 64 computed) against 29.6 ms with 512 (10 of 16): the f32
+# score tile is a quarter the size and less of the triangle is computed.
+CHUNK = 256
 
 
 # ---------------------------------------------------------------------------
@@ -59,28 +65,63 @@ def qkv_project(cfg, p, x):
 # repro.kernels.flash_attention and is numerically checked against this).
 # ---------------------------------------------------------------------------
 
-def _chunk_mask(qpos, kpos, causal: bool, window: Optional[int]):
-    """(qc, kc) boolean validity mask for a (q-chunk, kv-chunk) pair."""
-    m = jnp.ones((qpos.shape[0], kpos.shape[0]), bool)
+def _block_live(qi, ki, *, q_offset, q_chunk: int, kv_chunk: int, causal: bool,
+                window: Optional[int], kv_valid, prefix_len: int):
+    """Whether the (q chunk ``qi``, kv chunk ``ki``) block of
+    ``flash_attention`` can hold a valid (q, k) pair.
+
+    Decided from the block's first and last positions; False only where the
+    element-wise mask would hide every pair (with a prefix and a window it may
+    keep a block whose pairs are all hidden).  Takes Python ints or traced
+    values, so the same test runs inside the kv scan and in ``flash_blocks``.
+    """
+    q_lo = q_offset + qi * q_chunk
+    k_lo = ki * kv_chunk
+    live = k_lo < kv_valid
     if causal:
-        m &= qpos[:, None] >= kpos[None, :]
+        seen = k_lo <= q_lo + q_chunk - 1
+        if prefix_len:
+            seen = seen | (k_lo < prefix_len)
+        live = live & seen
     if window is not None:
-        m &= (qpos[:, None] - kpos[None, :]) < window
-    return m
+        live = live & (q_lo - (k_lo + kv_chunk - 1) < window)
+    return live
+
+
+def flash_blocks(Sq: int, Skv: int, *, causal: bool = True,
+                 window: Optional[int] = None, q_offset: int = 0,
+                 q_chunk: int = CHUNK, kv_chunk: int = CHUNK,
+                 kv_len: Optional[int] = None, prefix_len: int = 0):
+    """(blocks computed, blocks in the grid) of one ``flash_attention`` call
+    with q of length ``Sq`` and k/v of length ``Skv``; a block spans every
+    batch row and head.  The options are ``flash_attention``'s."""
+    q_chunk, kv_chunk = min(q_chunk, Sq), min(kv_chunk, Skv)
+    nq, nk = -(-Sq // q_chunk), -(-Skv // kv_chunk)
+    live = sum(bool(_block_live(
+        qi, ki, q_offset=q_offset, q_chunk=q_chunk, kv_chunk=kv_chunk,
+        causal=causal, window=window,
+        kv_valid=Skv if kv_len is None else kv_len, prefix_len=prefix_len))
+        for qi in range(nq) for ki in range(nk))
+    return live, nq * nk
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     q_offset: int = 0,
-                    q_chunk: int = 512, kv_chunk: int = 512,
+                    q_chunk: int = CHUNK, kv_chunk: int = CHUNK,
                     kv_len: Optional[jax.Array] = None,
                     prefix_len: int = 0):
     """Blockwise attention with online softmax.
 
     q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D); Hq % Hkv == 0.
     ``q_offset``: absolute position of q[0] relative to k[0] (prefill=0;
-    decode-with-history > 0).  ``kv_len``: optional dynamic valid KV length.
-    Returns (B, Sq, Hq, D) in q.dtype.
+    decode-with-history > 0; may be traced).  ``kv_len``: optional dynamic
+    valid KV length.  A (q, kv) block whose pairs the mask hides entirely
+    (``_block_live``) is skipped: a ``lax.cond`` carries the online softmax
+    state past it unchanged.  The gradient is a custom VJP that keeps only the
+    output and each row's log-sum-exp, recomputes each live block's
+    probabilities, and skips the same blocks.  Returns (B, Sq, Hq, D) in
+    q.dtype.
     """
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
@@ -102,49 +143,116 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kb = k.reshape(B, nk, kv_chunk, Hkv, D)
     vb = v.reshape(B, nk, kv_chunk, Hkv, D)
 
-    kv_valid = jnp.asarray(Skv if kv_len is None else kv_len)
+    live = functools.partial(_block_live, q_chunk=q_chunk, kv_chunk=kv_chunk,
+                             causal=causal, window=window, prefix_len=prefix_len)
 
-    def q_step(_, qi):
-        qc = qb[:, qi]                           # (B, qc, Hkv, G, D)
+    def block_scores(qc, kc, qi, ki, q_offset, kv_valid):
+        """Scaled f32 scores of one block, hidden pairs at NEG_INF."""
         qpos = q_offset + qi * q_chunk + jnp.arange(q_chunk)
+        kpos = ki * kv_chunk + jnp.arange(kv_chunk)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qc, kc,
+                       preferred_element_type=jnp.float32) * scale
+        valid = jnp.ones((q_chunk, kv_chunk), bool)
+        if causal:
+            cm = qpos[:, None] >= kpos[None, :]
+            if prefix_len:  # prefix-LM: the prefix is fully visible
+                cm |= (kpos < prefix_len)[None, :]
+            valid &= cm
+        if window is not None:
+            valid &= (qpos[:, None] - kpos[None, :]) < window
+        valid &= (kpos < kv_valid)[None, :]
+        return jnp.where(valid[None, None, None], s, NEG_INF)
 
-        def kv_step(carry, ki):
-            m_prev, l_prev, acc = carry
-            kc = kb[:, ki]                       # (B, kc, Hkv, D)
-            vc = vb[:, ki]
-            kpos = ki * kv_chunk + jnp.arange(kv_chunk)
-            s = jnp.einsum("bqhgd,bkhd->bhgqk", qc, kc,
-                           preferred_element_type=jnp.float32) * scale
-            valid = jnp.ones((q_chunk, kv_chunk), bool)
-            if causal:
-                cm = qpos[:, None] >= kpos[None, :]
-                if prefix_len:  # prefix-LM: the prefix is fully visible
-                    cm |= (kpos < prefix_len)[None, :]
-                valid &= cm
-            if window is not None:
-                valid &= (qpos[:, None] - kpos[None, :]) < window
-            valid &= (kpos < kv_valid)[None, :]
-            s = jnp.where(valid[None, None, None], s, NEG_INF)
-            m_cur = jnp.max(s, axis=-1)                      # (B,Hkv,G,qc)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new[..., None])
-            l_cur = jnp.sum(p, axis=-1)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + l_cur
-            pv = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(vc.dtype), vc,
-                            preferred_element_type=jnp.float32)
-            acc = acc * alpha[..., None] + pv
-            return (m_new, l_new, acc), None
+    def forward(qb, kb, vb, q_offset, kv_valid):
+        """Output blocks (nq, B, qc, Hkv, G, D) and each row's log-sum-exp."""
+        def q_step(_, qi):
+            qc = qb[:, qi]                       # (B, qc, Hkv, G, D)
 
-        m0 = jnp.full((B, Hkv, G, q_chunk), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, Hkv, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, G, q_chunk, D), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nk))
-        out = acc / jnp.maximum(l[..., None], 1e-30)         # (B,Hkv,G,qc,D)
-        out = jnp.moveaxis(out, 3, 1)                        # (B,qc,Hkv,G,D)
-        return None, out.astype(q.dtype)
+            def attend_block(carry, ki):
+                m_prev, l_prev, acc = carry
+                s = block_scores(qc, kb[:, ki], qi, ki, q_offset, kv_valid)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+                pv = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(vb.dtype),
+                                vb[:, ki], preferred_element_type=jnp.float32)
+                return m_new, l_new, acc * alpha[..., None] + pv
 
-    _, outs = jax.lax.scan(q_step, None, jnp.arange(nq))     # (nq,B,qc,Hkv,G,D)
+            def kv_step(carry, ki):
+                carry = jax.lax.cond(
+                    live(qi, ki, q_offset=q_offset, kv_valid=kv_valid),
+                    attend_block, lambda c, _: c, carry, ki)
+                return carry, None
+
+            m0 = jnp.full((B, Hkv, G, q_chunk), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((B, Hkv, G, q_chunk), jnp.float32)
+            a0 = jnp.zeros((B, Hkv, G, q_chunk, D), jnp.float32)
+            (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
+                                          jnp.arange(nk))
+            l = jnp.maximum(l, 1e-30)
+            out = jnp.moveaxis(acc / l[..., None], 3, 1)  # (B,qc,Hkv,G,D)
+            return None, (out.astype(qb.dtype), m + jnp.log(l))
+
+        _, (outs, lse) = jax.lax.scan(q_step, None, jnp.arange(nq))
+        return outs, lse
+
+    @jax.custom_vjp
+    def attend(qb, kb, vb, q_offset, kv_valid):
+        return forward(qb, kb, vb, q_offset, kv_valid)[0]
+
+    def attend_fwd(qb, kb, vb, q_offset, kv_valid):
+        outs, lse = forward(qb, kb, vb, q_offset, kv_valid)
+        return outs, (qb, kb, vb, q_offset, kv_valid, outs, lse)
+
+    def attend_bwd(res, douts):
+        """Recompute each live block's probabilities from the saved
+        log-sum-exp and accumulate dq, dk, dv in f32; skipped blocks add 0."""
+        qb, kb, vb, q_offset, kv_valid, outs, lse = res
+        delta = jnp.einsum("nbqhgd,nbqhgd->nbhgq", douts, outs,
+                           preferred_element_type=jnp.float32)
+
+        def q_step(dkv, qi):
+            qc, doc = qb[:, qi], douts[qi]       # (B, qc, Hkv, G, D)
+
+            def grad_block(dq, ki):
+                kc, vc = kb[:, ki], vb[:, ki]
+                s = block_scores(qc, kc, qi, ki, q_offset, kv_valid)
+                p = jnp.exp(s - lse[qi][..., None])
+                dv = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(vc.dtype), doc,
+                                preferred_element_type=jnp.float32)
+                dp = jnp.einsum("bqhgd,bkhd->bhgqk", doc, vc,
+                                preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta[qi][..., None]) * scale).astype(qc.dtype)
+                dq = dq + jnp.einsum("bhgqk,bkhd->bqhgd", ds, kc,
+                                     preferred_element_type=jnp.float32)
+                dk = jnp.einsum("bhgqk,bqhgd->bkhd", ds, qc,
+                                preferred_element_type=jnp.float32)
+                return dq, (dk, dv)
+
+            def skip(dq, _):
+                z = jnp.zeros((B, kv_chunk, Hkv, D), jnp.float32)
+                return dq, (z, z)
+
+            def kv_step(dq, ki):
+                return jax.lax.cond(
+                    live(qi, ki, q_offset=q_offset, kv_valid=kv_valid),
+                    grad_block, skip, dq, ki)
+
+            dq0 = jnp.zeros((B, q_chunk, Hkv, G, D), jnp.float32)
+            dq, (dk, dv) = jax.lax.scan(kv_step, dq0, jnp.arange(nk))
+            dkv = (dkv[0] + jnp.moveaxis(dk, 0, 1),
+                   dkv[1] + jnp.moveaxis(dv, 0, 1))
+            return dkv, dq.astype(qb.dtype)
+
+        z = jnp.zeros(kb.shape, jnp.float32)
+        (dk, dv), dq = jax.lax.scan(q_step, (z, z), jnp.arange(nq))
+        return (jnp.moveaxis(dq, 0, 1), dk.astype(kb.dtype),
+                dv.astype(vb.dtype), None, None)
+
+    attend.defvjp(attend_fwd, attend_bwd)
+    outs = attend(qb, kb, vb, jnp.asarray(q_offset),
+                  jnp.asarray(Skv if kv_len is None else kv_len))
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * q_chunk, Hq, D)
     return out[:, :Sq]
 
@@ -190,7 +298,7 @@ def full_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
 def sp_flash_attention(q, k, v, *, mesh, dp_axes, seq_axes=("model",),
                        causal=True, window=None, prefix_len=0,
-                       q_chunk=512, kv_chunk=512):
+                       q_chunk=CHUNK, kv_chunk=CHUNK):
     """q, k, v: (B, S, H, D) with S sharded over seq_axes and B over
     dp_axes.  Returns (B, S, Hq, D) with the same sharding."""
     B, S, Hq, D = q.shape

@@ -2,12 +2,14 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro import models
 from repro.configs import ASSIGNED_ARCHS, get_smoke_config
-from repro.models.attention import flash_attention, full_attention
+from repro.models.attention import (flash_attention, flash_blocks,
+                                    full_attention)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -166,6 +168,110 @@ def test_flash_sliding_window_property(w, s):
                          kv_chunk=16)
     o2 = full_attention(q, k, v, causal=True, window=w)
     assert float(jnp.max(jnp.abs(o1 - o2))) < 2e-4
+
+
+# (Sq, Skv, chunk, options): each case skips some (q, kv) blocks
+MASK_CASES = {
+    "causal_q_offset": (24, 64, 8, dict(causal=True, q_offset=40)),
+    "prefix": (48, 48, 16, dict(causal=True, prefix_len=20)),
+    "kv_len_short": (40, 64, 16, dict(causal=True, kv_len=37)),
+    "window_under_chunk": (64, 64, 16, dict(causal=True, window=5)),
+    "window_not_causal": (64, 64, 16, dict(causal=False, window=5)),
+    "whole_rows_skipped": (32, 128, 16, dict(causal=True, q_offset=8,
+                                             kv_len=40)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_flash_equals_full_where_blocks_are_skipped(case):
+    sq, skv, c, kw = MASK_CASES[case]
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(sq * 7 + skv), 3)
+    q = jax.random.normal(k1, (2, sq, 4, 16))
+    k = jax.random.normal(k2, (2, skv, 2, 16))
+    v = jax.random.normal(k3, (2, skv, 2, 16))
+    fkw = {n: x for n, x in kw.items() if n != "q_offset"}
+    # q_offset traced, as sp_flash_attention passes it
+    o1 = jax.jit(lambda q, k, v, off: flash_attention(
+        q, k, v, q_offset=off, q_chunk=c, kv_chunk=c, **fkw))(
+        q, k, v, jnp.int32(kw.get("q_offset", 0)))
+    o2 = full_attention(q, k, v, **kw)
+    computed, total = flash_blocks(sq, skv, q_chunk=c, kv_chunk=c, **kw)
+    assert computed < total
+    assert float(jnp.max(jnp.abs(o1 - o2))) < 2e-4
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_flash_grad_equals_full(window):
+    """Training differentiates through flash_attention: its gradients match
+    the quadratic reference's, skipped blocks included."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(k1, (2, 40, 4, 16))
+    k = jax.random.normal(k2, (2, 40, 2, 16))
+    v = jax.random.normal(k3, (2, 40, 2, 16))
+    w = jax.random.normal(k4, (2, 40, 4, 16))
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, window=window, **kw) * w)
+
+    g1 = jax.grad(loss(flash_attention, q_chunk=16, kv_chunk=8),
+                  argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(full_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        assert float(jnp.max(jnp.abs(a - b))) < 2e-4
+
+
+def _needed_blocks(sq, skv, c, causal=True, window=None, q_offset=0,
+                   kv_len=None, prefix_len=0):
+    """Blocks holding at least one pair full_attention's mask keeps."""
+    qpos = q_offset + np.arange(sq)[:, None]
+    kpos = np.arange(skv)[None, :]
+    valid = kpos < (skv if kv_len is None else kv_len)
+    if causal:
+        valid = valid & ((qpos >= kpos) | (kpos < prefix_len))
+    if window is not None:
+        valid = valid & (qpos - kpos < window)
+    return sum(valid[i:i + c, j:j + c].any()
+               for i in range(0, sq, c) for j in range(0, skv, c))
+
+
+@pytest.mark.parametrize("n", [1, 3, 8])
+def test_flash_blocks_causal_triangle(n):
+    assert flash_blocks(n * 64, n * 64, q_chunk=64, kv_chunk=64) == (
+        n * (n + 1) // 2, n * n)
+
+
+@pytest.mark.parametrize("s,chunk,want", [(1920, 512, (10, 16)),
+                                          (1920, 256, (36, 64)),
+                                          (2048, 256, (36, 64))])
+def test_flash_blocks_prefill(s, chunk, want):
+    assert flash_blocks(s, s, q_chunk=chunk, kv_chunk=chunk) == want
+
+
+@pytest.mark.parametrize("window,band", [(1, 1), (5, 2), (17, 2), (18, 3)])
+def test_flash_blocks_sliding_window_band(window, band):
+    """Only the window's band of blocks: the diagonal and the blocks left of
+    it that a window reaches into."""
+    n = 8
+    want = sum(min(band, i + 1) for i in range(n))
+    assert flash_blocks(n * 16, n * 16, q_chunk=16, kv_chunk=16,
+                        window=window) == (want, n * n)
+
+
+@pytest.mark.parametrize("kv_len", [1, 16, 17, 100])
+def test_flash_blocks_none_beyond_kv_len(kv_len):
+    computed, total = flash_blocks(64, 128, q_chunk=16, kv_chunk=16,
+                                   causal=False, kv_len=kv_len)
+    assert (computed, total) == (4 * -(-kv_len // 16), 32)
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_flash_blocks_matches_the_mask(case):
+    """The count is exact: a block is computed iff the mask keeps one of its
+    pairs (no case here combines a prefix with a window)."""
+    sq, skv, c, kw = MASK_CASES[case]
+    assert flash_blocks(sq, skv, q_chunk=c, kv_chunk=c, **kw)[0] == \
+        _needed_blocks(sq, skv, c, **kw)
 
 
 def test_attention_is_permutation_equivariant_over_batch():
