@@ -21,8 +21,8 @@ regression.  On foreign hosts the microbench's own ``--min-speedup``
 floor is the (host-independent) gate.
 
 Individual metrics can override the tolerance via ``TOLERANCE_OVERRIDES``
-(longest key-prefix match per bench file) — so noisy wall-clock sweep
-metrics gate loose while deterministic simulated outputs in the same doc
+(longest key-prefix match per bench file) — so a noisy harness wall
+clock gates loose while deterministic simulated outputs in the same doc
 gate tight.
 
 Everything else (latency percentiles, byte counts, error percentages) is
@@ -53,7 +53,7 @@ BASELINE_DIR = BENCH_DIR / "baseline"
 # higher-is-better headline families (substring match on the metric key)
 HEADLINE = ("tokens_per_s", "tokens_per_J", "throughput_tok_s",
             "efficiency_tok_J", "speedup", "eff_impr",
-            "paged_vs_infinite_tput", "cells_per_s", "availability",
+            "paged_vs_infinite_tput", "availability",
             "goodput_retention")
 # lower-is-better families: real wall clocks (see microbench.py)
 LOWER_IS_BETTER = ("wall_ms",)
@@ -70,29 +70,10 @@ WALL_BENCH_TOL = 0.50
 # Per-metric tolerance overrides: (bench artifact name, flattened-key
 # prefix) -> tolerance; the longest matching prefix wins, and an
 # override beats both the CLI tolerance and the wall-clock widening.
-# This lets one doc mix metric classes: BENCH_sweep.json carries noisy
-# wall-clock-derived numbers (speedup / cells-per-second — loose) NEXT
-# TO deterministic simulated outputs (per-cell tokens_per_s — tight),
-# which the doc-level WALL_BENCH_TOL widening alone cannot express.
-# The table is documented in EXPERIMENTS.md §Sweep-throughput.
+# This lets one doc mix metric classes: a harness wall clock (loose) NEXT
+# TO deterministic simulated outputs (tight), which the doc-level
+# WALL_BENCH_TOL widening alone cannot express.
 TOLERANCE_OVERRIDES = {
-    # ratio of two wall clocks in the same run: steadier than absolute
-    # walls, but still host-scheduler noise on both sides
-    ("BENCH_sweep.json", "sweep_speedup"): 0.35,
-    ("BENCH_sweep.json", "sweep_speedup_prefill"): 0.35,
-    ("BENCH_sweep.json", "sweep_speedup_lifted"): 0.35,
-    ("BENCH_sweep.json", "cells_per_s"): 0.50,
-    ("BENCH_sweep.json", "cells_per_s.vector"): 0.50,
-    ("BENCH_sweep.json", "wall_ms"): 0.50,
-    # deterministic simulator outputs: exact, gate tight even though
-    # the doc carries a host calibration
-    ("BENCH_sweep.json", "tokens_per_s"): 0.10,
-    # the sweep-grid microbench speedups (prefill cruise / lifted-knob
-    # grids) are wall ratios like the rest of BENCH_speed.json but much
-    # larger (20-80x), so relative jitter runs wider than the 3-15x
-    # engine-path cases the 0.50 doc tolerance was sized for
-    ("BENCH_speed.json", "speedup.sweep_prefill"): 0.40,
-    ("BENCH_speed.json", "speedup.sweep_lifted"): 0.40,
     # fleet doc (ISSUE 9): carries host_ops_per_s, so the doc-level
     # WALL_BENCH_TOL widening applies — pin the deterministic simulated
     # metrics back to tight and leave only the harness wall loose.

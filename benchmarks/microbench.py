@@ -133,159 +133,6 @@ def bench_paged_path(smoke: bool, repeats: int):
                              chunked_prefill_tokens=512), repeats)
 
 
-def bench_sweep_path(smoke: bool, repeats: int):
-    """Batched sweep engine vs the scalar fast engine cell-by-cell
-    (ISSUE 7): the same paged grid both ways, asserted report- and
-    kv_stats-identical per cell before timing.  The scalar side
-    constructs a fresh simulator + engine per cell — exactly what every
-    sweep bench did before launch/sweep_engine existed."""
-    import copy
-    import dataclasses
-    from repro.configs import get_config
-    from repro.core import PicnicSimulator
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.serving_engine import ContinuousBatchingEngine
-    from repro.launch.sweep_engine import SweepCell, sweep_serve
-    from repro.runtime.kv_cache import kv_cache_from_model
-    cfg = get_config("llama3.2-1b")
-    kvc = dataclasses.replace(
-        kv_cache_from_model(cfg, kv_frac=0.5, dram_frac=1.0),
-        block_tokens=1024, n_blocks=24, dram_blocks=24)
-    sim = PicnicSimulator()
-    sim.ccpg_model.include_dram_hub = True
-    ctxs = (256,) if smoke else (256, 512)
-    mns = (1024,) if smoke else (512, 1024)
-    cells = [SweepCell(f"c{ctx}r{rate}b{mb}n{mn}", cfg,
-                       Trace.poisson(6, rate_rps=rate, seed=0,
-                                     prompt_len=ctx, max_new=mn),
-                       ServingConfig(max_batch=mb, ccpg=True, kv_cache=kvc,
-                                    chunked_prefill_tokens=512), sim=sim)
-             for ctx in ctxs for rate in (20, 60) for mb in (4, 8)
-             for mn in mns]
-
-    def scalar():
-        out = []
-        for c in cells:
-            s2 = PicnicSimulator()
-            s2.ccpg_model.include_dram_hub = True
-            eng = ContinuousBatchingEngine(c.cfg, sim=s2, engine=c.engine)
-            rep = eng.run([copy.copy(r) for r in c.trace])
-            out.append((rep, eng.kv_stats))
-        return out
-
-    res = sweep_serve(cells)
-    for c, r, (rep, st) in zip(cells, res, scalar()):
-        assert r.report.row() == rep.row(), \
-            f"sweep cell {c.key}: batched engine diverged from scalar"
-        assert r.kv_stats.row() == st.row(), \
-            f"sweep cell {c.key}: batched kv_stats diverged from scalar"
-
-    wall_fast, _ = _best_wall(lambda: sweep_serve(cells), repeats)
-    wall_ref, _ = _best_wall(scalar, repeats)
-    tokens = sum(r.report.tokens_generated + r.report.tokens_prefilled
-                 for r in res)
-    return {
-        "name": "sweep",
-        "n_cells": len(cells),
-        "sim_tokens": tokens,
-        "wall_fast_s": wall_fast,
-        "wall_reference_s": wall_ref,
-        "speedup": wall_ref / wall_fast,
-        "tokens_per_wall_s_fast": tokens / wall_fast,
-        "tokens_per_wall_s_reference": tokens / wall_ref,
-    }
-
-
-def _sweep_grid_case(name, cells, repeats, floor):
-    """Batched sweep vs per-cell scalar engines (fresh simulator each,
-    the pre-sweep_engine execution model): identity asserted per cell
-    and ``fallback is None`` enforced before timing.  ``floor`` is the
-    case's own host-independent --min-speedup gate (the generic 3x
-    floor is far below what these vectorized grids must sustain)."""
-    import copy
-    from repro.core import PicnicSimulator
-    from repro.launch.serving_engine import ContinuousBatchingEngine
-    from repro.launch.sweep_engine import sweep_serve
-
-    def scalar():
-        out = []
-        for c in cells:
-            eng = ContinuousBatchingEngine(c.cfg, sim=PicnicSimulator(),
-                                           engine=c.engine)
-            out.append(eng.run([copy.copy(r) for r in c.trace]))
-        return out
-
-    res = sweep_serve(cells)
-    for c, r, rep in zip(cells, res, scalar()):
-        assert r.fallback is None, (c.key, r.fallback)
-        assert r.report.row() == rep.row(), \
-            f"{name} cell {c.key}: batched engine diverged from scalar"
-    wall_fast, _ = _best_wall(lambda: sweep_serve(cells), repeats)
-    wall_ref, _ = _best_wall(scalar, repeats)
-    tokens = sum(r.report.tokens_generated + r.report.tokens_prefilled
-                 for r in res)
-    return {
-        "name": name,
-        "n_cells": len(cells),
-        "sim_tokens": tokens,
-        "wall_fast_s": wall_fast,
-        "wall_reference_s": wall_ref,
-        "speedup": wall_ref / wall_fast,
-        "floor": floor,
-        "tokens_per_wall_s_fast": tokens / wall_fast,
-        "tokens_per_wall_s_reference": tokens / wall_ref,
-    }
-
-
-def bench_sweep_prefill_path(smoke: bool, repeats: int):
-    """Prefill-heavy / short-generation sweep grid (ISSUE 8): long
-    prompts chunk-streamed 64 tokens at a time, one or two generated
-    tokens — the regime PR 7 left on python-per-step scalar costs.  The
-    prefill cruise folds each request's full-cap chunk streak into one
-    closed-form array pass, so the sustainable floor sits an order of
-    magnitude above the generic 3x gate."""
-    from repro.configs import get_config
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.sweep_engine import SweepCell
-    cfg = get_config("llama3.2-1b")
-    ctx = 16384 if smoke else 32768
-    rates = (2, 16) if smoke else (1, 4, 16, 64)
-    cells = [SweepCell(f"pf{ctx}_r{rate}_n{mn}_s{sd}", cfg,
-                       Trace.poisson(2, rate_rps=rate, seed=sd,
-                                     prompt_len=ctx, max_new=mn),
-                       ServingConfig(max_batch=8, ccpg=True,
-                                    chunked_prefill_tokens=64))
-             for rate in rates for mn in (1, 2) for sd in (0, 1)]
-    # ~43x full / ~19x smoke on the baseline host
-    return _sweep_grid_case("sweep_prefill", cells, repeats,
-                            floor=8.0 if smoke else 20.0)
-
-
-def bench_sweep_lifted_path(smoke: bool, repeats: int):
-    """The previously-fallback knobs — overlap in (0,1], dynamic CCPG,
-    TTFT deadlines — on the vector path (ISSUE 8 lift): decode-heavy
-    cells exercising the split-cost lane, wake residue columns and the
-    at-risk burst horizon, still bit-identical and well above the
-    generic floor."""
-    from repro.configs import get_config
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.sweep_engine import SweepCell
-    cfg = get_config("llama3.2-1b")
-    mn = 2048 if smoke else 4096
-    cells = [SweepCell(f"lift_o{ov}_d{int(dyn)}_t{tt}", cfg,
-                       Trace.poisson(6, rate_rps=40, seed=0,
-                                     prompt_len=256, max_new=mn,
-                                     **({} if tt is None
-                                        else dict(deadline_ttft=tt))),
-                       ServingConfig(max_batch=8, overlap=ov, ccpg=True,
-                                    dynamic_ccpg=dyn))
-             for ov in (0.25, 0.75) for dyn in (False, True)
-             for tt in (None, 0.25)]
-    # ~27x full / ~16x smoke on the baseline host
-    return _sweep_grid_case("sweep_lifted", cells, repeats,
-                            floor=6.0 if smoke else 10.0)
-
-
 def bench_table_ii_path(smoke: bool, repeats: int):
     """The analytic Table-II walk: columnar vs object TimelineIR (the
     cycle-model memo hits across the 9-row sweep's repeated shapes)."""
@@ -349,9 +196,6 @@ def main() -> int:
         bench_serving_path(args.smoke, repeats),
         bench_paged_path(args.smoke, repeats),
         bench_table_ii_path(args.smoke, repeats),
-        bench_sweep_path(args.smoke, repeats),
-        bench_sweep_prefill_path(args.smoke, repeats),
-        bench_sweep_lifted_path(args.smoke, repeats),
     ]
 
     doc = {
@@ -372,7 +216,7 @@ def main() -> int:
                 for c in cases},
             "events_per_wall_s": {
                 c["name"]: round(c["events_per_wall_s_fast"], 1)
-                for c in cases if "events_per_wall_s_fast" in c},
+                for c in cases},
         },
         "rows": cases,
     }
@@ -386,20 +230,16 @@ def main() -> int:
         print(f"{c['name']},{c['speedup']:.2f},"
               f"{c['tokens_per_wall_s_fast']:.0f},"
               f"{c['tokens_per_wall_s_reference']:.0f},"
-              f"{c.get('events_per_wall_s_fast', float('nan')):.0f}")
+              f"{c['events_per_wall_s_fast']:.0f}")
     print(f"wrote {args.out}")
 
     if args.min_speedup is not None:
-        # a case can carry its own higher "floor" (the vectorized sweep
-        # grids must hold far more than the generic 3x)
-        slow = [c for c in cases
-                if c["speedup"] < max(args.min_speedup,
-                                      c.get("floor", 0.0))]
+        slow = [c for c in cases if c["speedup"] < args.min_speedup]
         if slow:
             print(f"SPEED REGRESSION: "
                   f"{[(c['name'], round(c['speedup'], 1)) for c in slow]} "
                   f"below the fast-vs-reference floor (--min-speedup "
-                  f"{args.min_speedup} or the case's own floor)")
+                  f"{args.min_speedup})")
             return 1
     return 0
 

@@ -250,14 +250,10 @@ def bench_fig10_timeline():
 def bench_serving():
     """Continuous-batching serving engine: the same 64-request Poisson
     trace (Llama-1B 512/64) served 1-at-a-time vs batch-8, ccpg off/on.
-    Headline: batched decode throughput at batch 8 vs sequential.  The
-    four cells run as one batched pass through launch/sweep_engine
-    (byte-identical to the scalar engine per cell — locked by the sweep
-    differential suite)."""
+    Headline: batched decode throughput at batch 8 vs sequential."""
     from repro.configs import get_config
     from repro.core import PicnicSimulator
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.sweep_engine import SweepCell, sweep_serve
+    from repro.launch import ServingConfig, SweepCell, Trace, sweep
     t0 = time.time()
     cfg = get_config("llama3.2-1b")
     sim = PicnicSimulator()
@@ -268,7 +264,7 @@ def bench_serving():
                        ServingConfig(max_batch=c["max_batch"],
                                      ccpg=c["ccpg"]), sim=sim)
              for c in grid]
-    results = sweep_serve(cells)
+    results = sweep(cells)
     rows = [{"max_batch": c["max_batch"], **r.report.row()}
             for c, r in zip(grid, results)]
     tput = {(c["max_batch"], c["ccpg"]): r.report.tokens_per_s
@@ -301,8 +297,7 @@ def bench_paged():
     recovers the batch occupancy that long shared system prompts cost."""
     from repro.configs import get_config
     from repro.core import PicnicSimulator
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.sweep_engine import SweepCell, sweep_serve
+    from repro.launch import ServingConfig, SweepCell, Trace, sweep
     from repro.runtime.kv_cache import kv_cache_from_model
     t0 = time.time()
     arch = "llama3.2-1b"
@@ -341,7 +336,7 @@ def bench_paged():
             ServingConfig(max_batch=8, ccpg=True, kv_cache=kv,
                           chunked_prefill_tokens=512 if kv else 0),
             sim=sim_hub if c["paged"] else sim_plain))
-    results = sweep_serve(cells)
+    results = sweep(cells)
 
     rows, tput, mean_batch = [], {}, {}
     for c, res in zip(grid, results):
@@ -383,155 +378,6 @@ def bench_paged():
     }, rows=rows)
     _emit("paged", t0, f"paged_vs_infinite_tput_at_8k={keep:.3f} "
                        f"prefix_batch_recovery_speedup={recovery:.2f}x")
-    return rows
-
-
-def _sweep_grid_vs_scalar(cells):
-    """One sweep grid both ways: batched SweepEngine vs the scalar fast
-    engine cell-by-cell with a fresh simulator per cell (exactly how
-    this harness executed sweeps before launch/sweep_engine existed).
-    Asserts per-cell report identity and a fallback-free vector path
-    before any number is recorded, so the speedup can never be bought
-    with a behavior change.  Returns (results, engine, t_sweep_s,
-    t_scalar_s)."""
-    import copy
-    from repro.core import PicnicSimulator
-    from repro.launch.serving_engine import ContinuousBatchingEngine
-    from repro.launch.sweep_engine import SweepEngine
-    eng = SweepEngine(cells)
-    t_sw = time.perf_counter()
-    results = eng.run()
-    t_sw = time.perf_counter() - t_sw
-    t_sc = time.perf_counter()
-    refs = []
-    for c in cells:
-        s2 = PicnicSimulator()
-        if c.sim is not None and c.sim.ccpg_model.include_dram_hub:
-            s2.ccpg_model.include_dram_hub = True
-        ref = ContinuousBatchingEngine(c.cfg, sim=s2, engine=c.engine)
-        refs.append(ref.run([copy.copy(r) for r in c.trace]))
-    t_sc = time.perf_counter() - t_sc
-    for c, res, ref in zip(cells, results, refs):
-        assert res.fallback is None, (c.key, res.fallback)
-        assert res.report.row() == ref.row(), \
-            f"sweep cell {c.key}: batched engine diverged from scalar"
-    return results, eng, t_sw, t_sc
-
-
-def bench_sweep():
-    """Vectorized sweep engine (ISSUE 7 tentpole, ISSUE 8 finish): three
-    grids through launch/sweep_engine vs the scalar engine per cell.
-
-      * decode grid — 64 paged cells, ctx x arrival-rate x max_batch x
-        max_new in the long-generation decode regime (reasoning-style
-        workloads, coarse 2048-token KV blocks);
-      * prefill grid — 64 prefill-heavy/short-generation cells (32k
-        prompts streamed in 64-token chunks, 1-2 generated tokens), the
-        regime the prefill cruise vectorizes;
-      * lifted grid — 16 decode-heavy cells over the previously-fallback
-        knobs (overlap in (0,1], dynamic CCPG, TTFT deadlines), now on
-        the vector path.
-
-    The doc carries the host-calibration fingerprint (see
-    microbench.py); wall-derived speedups gate loose and per-cell
-    tokens_per_s values are deterministic simulated outputs gating tight
-    via the check_regression.py TOLERANCE_OVERRIDES table.
-    ``cells_per_s`` is split vector vs scalar-fallback wall time (the
-    fallback share no longer silently dilutes the headline) and the
-    summary line carries the per-reason fallback counts."""
-    from repro.configs import get_config
-    from repro.core import PicnicSimulator
-    from repro.launch import ServingConfig, Trace
-    from repro.launch.sweep_engine import SweepCell
-    from repro.runtime.kv_cache import kv_cache_from_model
-    try:
-        from benchmarks.microbench import _host_calibration
-    except ImportError:                     # `python benchmarks/run.py`
-        from microbench import _host_calibration
-    t0 = time.time()
-    cfg = get_config("llama3.2-1b")
-    cal = _host_calibration()
-
-    kvc = dataclasses.replace(
-        kv_cache_from_model(cfg, kv_frac=0.5, dram_frac=1.0),
-        block_tokens=2048, n_blocks=24, dram_blocks=24)
-    sim = PicnicSimulator()
-    sim.ccpg_model.include_dram_hub = True
-    grid = cell_grid({"ctx": (256, 1024),
-                      "rate_rps": (10, 20, 30, 40, 50, 60, 80, 100),
-                      "max_batch": (4, 8), "max_new": (2048, 4096)},
-                     abbrev={"rate_rps": "r", "max_batch": "b",
-                             "max_new": "n"})
-    dec_cells = [SweepCell(c.key(), cfg,
-                           Trace.poisson(6, rate_rps=c["rate_rps"], seed=0,
-                                         prompt_len=c["ctx"],
-                                         max_new=c["max_new"]),
-                           ServingConfig(max_batch=c["max_batch"], ccpg=True,
-                                         kv_cache=kvc,
-                                         chunked_prefill_tokens=512),
-                           sim=sim)
-                 for c in grid]
-    pf_cells = [SweepCell(f"pf_r{rate}_n{mn}_s{sd}", cfg,
-                          Trace.poisson(2, rate_rps=rate, seed=sd,
-                                        prompt_len=32768, max_new=mn),
-                          ServingConfig(max_batch=8, ccpg=True,
-                                        chunked_prefill_tokens=64))
-                for rate in (1, 2, 4, 8, 16, 32, 64, 128)
-                for mn in (1, 2) for sd in (0, 1, 2, 3)]
-    lift_cells = [SweepCell(f"lift_o{ov}_d{int(dyn)}_t{tt}_r{rate}", cfg,
-                            Trace.poisson(6, rate_rps=rate, seed=0,
-                                          prompt_len=256, max_new=4096,
-                                          **({} if tt is None
-                                             else dict(deadline_ttft=tt))),
-                            ServingConfig(max_batch=8, overlap=ov,
-                                          ccpg=True, dynamic_ccpg=dyn))
-                  for ov in (0.25, 0.75) for dyn in (False, True)
-                  for tt in (None, 0.25) for rate in (30, 60)]
-
-    dec_res, dec_eng, dec_sw, dec_sc = _sweep_grid_vs_scalar(dec_cells)
-    pf_res, pf_eng, pf_sw, pf_sc = _sweep_grid_vs_scalar(pf_cells)
-    lf_res, lf_eng, lf_sw, lf_sc = _sweep_grid_vs_scalar(lift_cells)
-
-    engines = (dec_eng, pf_eng, lf_eng)
-    n_cells = len(dec_cells) + len(pf_cells) + len(lift_cells)
-    fb_counts: dict = {}
-    for e in engines:
-        for reason, cnt in e.fallback_counts.items():
-            fb_counts[reason] = fb_counts.get(reason, 0) + cnt
-    n_fb = sum(fb_counts.values())
-    vec_wall = sum(e.vector_wall_s for e in engines)
-    fb_wall = sum(e.fallback_wall_s for e in engines)
-    speedup = dec_sc / dec_sw
-    pf_speedup = pf_sc / pf_sw
-    lf_speedup = lf_sc / lf_sw
-
-    pairs = list(zip(dec_cells, dec_res)) + list(zip(pf_cells, pf_res)) \
-        + list(zip(lift_cells, lf_res))
-    rows = [{"cell": c.key, **r.report.row()} for c, r in pairs]
-    _save("sweep", rows)
-    _bench_artifact("sweep", {
-        "sweep_speedup_64cell": round(speedup, 2),
-        "sweep_speedup_prefill_64cell": round(pf_speedup, 2),
-        "sweep_speedup_lifted_16cell": round(lf_speedup, 2),
-        # vector vs scalar-fallback wall split: every cell of every grid
-        # rides the vector path, so the fallback share must stay zero
-        "cells_per_s": {
-            "vector": round((n_cells - n_fb) / vec_wall, 1),
-            "fallback": round(n_fb / fb_wall, 1) if fb_wall else 0.0},
-        "wall_ms": {"sweep": round(dec_sw * 1e3, 1),
-                    "scalar_per_cell": round(dec_sc * 1e3, 1),
-                    "prefill_sweep": round(pf_sw * 1e3, 1),
-                    "prefill_scalar_per_cell": round(pf_sc * 1e3, 1),
-                    "lifted_sweep": round(lf_sw * 1e3, 1),
-                    "lifted_scalar_per_cell": round(lf_sc * 1e3, 1),
-                    "fallback": round(fb_wall * 1e3, 1)},
-        "n_cells": n_cells,
-        "fallback_cells": n_fb,
-        "tokens_per_s": {c.key: r.report.tokens_per_s for c, r in pairs},
-    }, rows=rows, extra={"host_ops_per_s": round(cal, 1)})
-    _emit("sweep", t0,
-          f"speedup decode={speedup:.1f}x prefill={pf_speedup:.1f}x "
-          f"lifted={lf_speedup:.1f}x fallback_cells={n_fb} ({fb_counts})")
     return rows
 
 
@@ -780,28 +626,6 @@ def bench_distributed():
     return out
 
 
-def bench_roofline():
-    """The dry-run roofline table (reads artifacts/dryrun/*.json)."""
-    t0 = time.time()
-    dry = ROOT / "artifacts" / "dryrun"
-    rows = []
-    for f in sorted(dry.glob("*.json")):
-        r = json.loads(f.read_text())
-        if r.get("status") != "ok":
-            continue
-        rows.append({
-            "cell": r["cell"], "variant": r.get("variant", "baseline"),
-            **{k: round(v, 4) for k, v in r["roofline"].items()},
-            "dominant": r["dominant"],
-            "useful_flop_frac": round(r.get("useful_flop_frac") or 0, 3),
-        })
-    _save("roofline", rows)
-    n_base = sum(1 for r in rows if r["variant"] == "baseline")
-    n_opt = len(rows) - n_base
-    _emit("roofline", t0, f"cells_baseline={n_base}_optimized={n_opt}")
-    return rows
-
-
 def bench_kernels():
     """Microbenchmarks of the Pallas kernels (interpret mode on CPU: the
     number that matters here is allclose-to-oracle; wall time is recorded
@@ -930,11 +754,9 @@ BENCHES = {
     "fig10_timeline": bench_fig10_timeline,
     "serving": bench_serving,
     "paged": bench_paged,
-    "sweep": bench_sweep,
     "fleet": bench_fleet,
     "chaos": bench_chaos,
     "distributed": bench_distributed,
-    "roofline": bench_roofline,
     "kernels": bench_kernels,
     "ablations": bench_ablations,
 }

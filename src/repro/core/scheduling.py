@@ -23,8 +23,6 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from .energy import TileSpec
 from .mapping import map_layer
 from .noc import Mesh2D, MeshConfig
@@ -379,40 +377,6 @@ class CycleModel:
         return (base + n_attn * int(self.ctx_cycles_per_pos * ctx_sum),
                 c2c_cyc, c2c_bytes)
 
-    def decode_affine_split(self, cfg, alloc: ChipletAllocation, b: int
-                            ) -> Optional[Tuple[int, int, int, int,
-                                                float, float, int]]:
-        """Like :meth:`decode_affine` but with the serialized C2C cycles
-        kept SEPARATE from the compute base: ``(base_compute_cycles,
-        n_attn, c2c_cycles, c2c_bytes, ctx_cycles_per_pos, alpha,
-        cal_ver)`` such that one batch-``b`` iteration at C2C overlap
-        fraction ``ov`` costs exactly
-
-            int((base_compute + n_attn * int(cpp * ctx_sum)
-                 + (1.0 - ov) * c2c_cycles) * alpha)
-
-        — the :meth:`batched_token_decode_cycles` ``overlap`` branch as
-        plain arithmetic (the sweep engine's vectorized split-cost lane).
-        At ``ov == 0`` the scalar engine folds ``c2c_cycles`` into the
-        base as an exact int sum instead; both reductions are reproduced
-        bit-for-bit from this decomposition.  ``None`` when memoization
-        is off or the cost is non-affine."""
-        if not self.memoize or b <= 0:
-            return None
-        key = self._decode_key(cfg, alloc, b)
-        hot = self._decode_hot
-        entry = hot[1] if (hot is not None and hot[0] == key) \
-            else self._decode_memo.get(key)
-        if entry is None:
-            self._decode_hot = None      # force split() to (re)build
-            self.batched_token_decode_cycles_split(cfg, alloc, [0] * b)
-            entry = self._decode_memo[key]
-        base, n_attn, c2c_cyc, c2c_bytes, _ = entry
-        if n_attn is None:
-            return None
-        return (base, n_attn, c2c_cyc, c2c_bytes,
-                self.ctx_cycles_per_pos, self.alpha, self._cal_ver)
-
     def decode_affine(self, cfg, alloc: ChipletAllocation, b: int
                       ) -> Optional[Tuple[int, int, int, float, float, int]]:
         """Fast-path export of the memoized decode decomposition:
@@ -530,159 +494,3 @@ class CycleModel:
         cyc = stream_cyc + attn_cyc + fill
         c2c_bytes = chunk * d * max(0, alloc.n_chiplets - 1)
         return int(cyc * self.alpha), c2c_bytes
-
-
-# ---------------------------------------------------------------------------
-# Batched cost surface (the sweep engine's cell-major view)
-# ---------------------------------------------------------------------------
-
-class DecodeCostSurface:
-    """Cell-major batched view of :meth:`CycleModel.decode_affine`.
-
-    Where ``decode_affine`` exports the affine decode decomposition for one
-    ``(alloc, b)`` at a time, the surface tabulates it for every batch size
-    ``1..max_batch`` so a whole grid of cells can price one decode round in
-    a handful of numpy ops::
-
-        cyc = int((base[b] + n_attn[b] * int(cpp * ctx_sum)) * alpha)
-
-    evaluated elementwise over cell vectors ``b_vec`` / ``ctx_sum_vec``.
-    Each lane performs exactly the scalar engine's arithmetic (same
-    truncation points, same float64 ops), so per-cell results are
-    bit-identical to pricing the cells one at a time.
-
-    The surface shares the model's memo (building it populates the decode
-    LRU; rebuilds after a hit are O(1) lookups) and its invalidation
-    story: ``cal_ver`` snapshots the model's ``__setattr__`` calibration
-    stamp, so mutating ``alpha`` & friends on the shared model invalidates
-    every cell of every sweep at once — callers re-validate with
-    :meth:`refresh` before each use.
-
-    ``affine[b]`` is False for batch sizes where a subclass made the cost
-    non-affine (or ``memoize`` is off, in which case every lane is False);
-    cells at those batch sizes must fall back to the scalar walk.
-    """
-
-    def __init__(self, model: CycleModel, cfg, alloc: ChipletAllocation,
-                 max_batch: int):
-        if max_batch < 1:
-            raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-        self.model = model
-        self.cfg = cfg
-        self.alloc = alloc
-        self.max_batch = int(max_batch)
-        self._build()
-
-    # chunk/ctx_before shapes the closed-form prefill lane is verified
-    # against the model's own pricing at build time; any mismatch (a
-    # subclass overriding the walk) disables the lane for the surface
-    _PREFILL_PROBES = ((1, 0), (64, 0), (128, 4096), (257, 65537))
-
-    def _build(self) -> None:
-        m = self.model
-        n = self.max_batch + 1          # index directly by batch size
-        self.base = np.zeros(n, dtype=np.int64)
-        self.base_compute = np.zeros(n, dtype=np.int64)
-        self.c2c_cyc = np.zeros(n, dtype=np.int64)
-        self.n_attn = np.zeros(n, dtype=np.int64)
-        self.c2c_bytes = np.zeros(n, dtype=np.int64)
-        self.affine = np.zeros(n, dtype=bool)
-        for b in range(1, n):
-            aff = m.decode_affine_split(self.cfg, self.alloc, b)
-            if aff is None:
-                continue
-            base_c, n_attn, c2c_cyc, c2cb, _cpp, _alpha, _ver = aff
-            self.base[b] = base_c + c2c_cyc   # decode_affine's folded base
-            self.base_compute[b] = base_c
-            self.c2c_cyc[b] = c2c_cyc
-            self.n_attn[b] = n_attn
-            self.c2c_bytes[b] = c2cb
-            self.affine[b] = True
-        self.cpp = float(m.ctx_cycles_per_pos)
-        self.alpha = float(m.alpha)
-        self.cal_ver = m._cal_ver
-        self._build_prefill()
-
-    def _build_prefill(self) -> None:
-        """Snapshot the closed-form prefill-chunk constants and verify
-        them against the model's own pricing (`prefill_chunk_cycles`) at
-        a few probe shapes — a subclass overriding the walk silently
-        demotes the vectorized lane to the memo-backed gather."""
-        m, cfg, alloc = self.model, self.cfg, self.alloc
-        d = cfg.d_model
-        self._pf_smac = sum(m.smac_cycles(ld)
-                            for ld, _ in alloc.assignments)
-        self._pf_den = max(alloc.n_chiplets, 1)
-        self._pf_qd2 = 2.0 * (cfg.q_dim or d)
-        self._pf_nattn = sum(1 for ld, _ in alloc.assignments
-                             if ld.kind == "attn")
-        self._pf_lanes = m.mesh.dmac_lanes * 1024 * 0.5
-        self._pf_fill = len(alloc.assignments) * m.c2c_latency
-        self._pf_c2cb = d * max(0, alloc.n_chiplets - 1)
-        self.prefill_closed = True
-        for c, cb in self._PREFILL_PROBES:
-            want = m.prefill_chunk_cycles(cfg, alloc, c, cb)
-            got_c, got_b = self._prefill_closed_form(
-                np.array([c], dtype=np.int64),
-                np.array([cb], dtype=np.int64))
-            if (int(got_c[0]), int(got_b[0])) != want:
-                self.prefill_closed = False
-                break
-
-    def _prefill_closed_form(self, chunk: np.ndarray, before: np.ndarray
-                             ) -> Tuple[np.ndarray, np.ndarray]:
-        """`CycleModel._prefill_chunk_walk` as elementwise numpy — the
-        same float64 ops at the same points in the same order, so each
-        lane reproduces the scalar walk bit-for-bit."""
-        stream_cyc = chunk * self._pf_smac / self._pf_den
-        attn_macs = (self._pf_qd2 * chunk * (chunk + 1) / 2
-                     + self._pf_qd2 * chunk * before)
-        attn_cyc = self._pf_nattn * attn_macs / self._pf_lanes
-        cyc = stream_cyc + attn_cyc + self._pf_fill
-        c2cb = chunk * self._pf_c2cb
-        return (cyc * self.alpha).astype(np.int64), c2cb
-
-    def valid(self) -> bool:
-        return self.cal_ver == self.model._cal_ver
-
-    def refresh(self) -> bool:
-        """Rebuild iff the model's calibration stamp moved since the last
-        build.  Returns True when a rebuild happened (callers holding
-        per-cell snapshots of base/n_attn must re-gather)."""
-        if self.valid():
-            return False
-        self._build()
-        return True
-
-    def decode_cycles(self, b_vec, ctx_sum_vec) -> np.ndarray:
-        """Pre-CCPG cycles of one decode round per cell — vectorized over
-        cells.  ``b_vec`` are per-cell batch sizes (1..max_batch, affine
-        lanes only), ``ctx_sum_vec`` per-cell context sums."""
-        b = np.asarray(b_vec, dtype=np.int64)
-        ctx = np.asarray(ctx_sum_vec, dtype=np.int64)
-        cyc = self.base[b] + self.n_attn[b] * (self.cpp * ctx).astype(np.int64)
-        return (cyc.astype(np.float64) * self.alpha).astype(np.int64)
-
-    def prefill_chunk_cycles(self, chunk_vec, ctx_before_vec
-                             ) -> Tuple[np.ndarray, np.ndarray]:
-        """(cycles, c2c_bytes) per cell for prefill chunk shapes — array
-        in, array out.  When the build-time probes matched the model
-        (``prefill_closed``), shapes are priced by the closed-form walk
-        vectorized directly over the array — no memo traffic at all;
-        otherwise (a subclass overrode the walk) each lane gathers
-        through the model's shared prefill LRU."""
-        chunk = np.asarray(chunk_vec, dtype=np.int64)
-        before = np.asarray(ctx_before_vec, dtype=np.int64)
-        if chunk.shape != before.shape:
-            raise ValueError("chunk/ctx_before shape mismatch")
-        if self.prefill_closed:
-            return self._prefill_closed_form(chunk, before)
-        cyc = np.empty(chunk.shape, dtype=np.int64)
-        c2cb = np.empty(chunk.shape, dtype=np.int64)
-        m, cfg, alloc = self.model, self.cfg, self.alloc
-        flat_c, flat_b = chunk.ravel(), before.ravel()
-        out_c, out_b = cyc.ravel(), c2cb.ravel()
-        for i in range(flat_c.size):
-            out_c[i], out_b[i] = m.prefill_chunk_cycles(
-                cfg, alloc, int(flat_c[i]), int(flat_b[i]))
-        return cyc, c2cb

@@ -38,19 +38,9 @@ one-dataclass-per-append recorder; both modes run the same float
 arithmetic in the same order, so they are byte-identical — locked by
 tests/test_fastpath.py.
 
-``aggregate_only=True`` goes one step further: NO event stream at all —
-only the running per-(class, kind) sums and per-class counts that the
-columnar recorder already maintains internally.  Same accumulator
-arithmetic in the same append order (so every derived aggregate stays
-bit-identical to the other modes), but reading ``events`` / ``column``
-/ the trace exporters raises.  This is the sweep-engine recorder: a
-grid of N cells keeps N aggregate-only timelines, mirrored into one
-cell-major :class:`SweepAggregates` array block while the vectorized
-round loop advances all cells at once.
-
 Aggregate queries (`cycles()` / `span_seconds()` / `count()` /
 `total_energy_J()`) read running per-(class, kind) sums maintained on
-append — O(1) instead of an O(E) event scan — in ALL modes.
+append — O(1) instead of an O(E) event scan — in both modes.
 """
 from __future__ import annotations
 
@@ -59,8 +49,6 @@ from collections import defaultdict
 from dataclasses import dataclass
 from typing import (Dict, Iterator, List, Optional, Sequence, Tuple, Type,
                     Union)
-
-import numpy as np
 
 from .interconnect import LinkSpec, OPTICAL, c2c_average_power
 
@@ -187,11 +175,9 @@ class Timeline:
     count aggregates behind `cycles()` / `span_seconds()` / `count()`.
     """
 
-    def __init__(self, link: LinkSpec = OPTICAL, *, columnar: bool = True,
-                 aggregate_only: bool = False):
+    def __init__(self, link: LinkSpec = OPTICAL, *, columnar: bool = True):
         self.link = link
         self.columnar = columnar
-        self.aggregate_only = aggregate_only
         self.now = 0.0
         self.energy_J = 0.0        # span-integrated chip energy
         self.busy_s = 0.0
@@ -205,9 +191,7 @@ class Timeline:
             defaultdict(int)
         self._span_s: Dict[Tuple[str, Optional[str]], float] = \
             defaultdict(float)
-        if aggregate_only:
-            self._counts = [0] * 8             # per-class append counts
-        elif columnar:
+        if columnar:
             # per-class parallel columns + one global class-id sequence;
             # dataclass events are materialized lazily from these
             self._seq: List[int] = []
@@ -223,11 +207,7 @@ class Timeline:
     def compute(self, dur_s: float, *, kind: str, power_W: float = 0.0,
                 cycles: int = 0, batch: int = 1, name: str = "") -> float:
         now = self.now
-        if self.aggregate_only:
-            cnt = self._counts
-            cnt[_COMPUTE] += 1
-            cnt[_SAMPLE] += 1
-        elif self.columnar:
+        if self.columnar:
             seq = self._seq
             seq.append(_COMPUTE)
             c = self._cols[_COMPUTE]
@@ -262,11 +242,7 @@ class Timeline:
     def wake(self, dur_s: float, *, power_W: float = 0.0, cycles: int = 0,
              cluster: int = -1) -> float:
         now = self.now
-        if self.aggregate_only:
-            cnt = self._counts
-            cnt[_WAKE] += 1
-            cnt[_SAMPLE] += 1
-        elif self.columnar:
+        if self.columnar:
             seq = self._seq
             seq.append(_WAKE)
             c = self._cols[_WAKE]
@@ -292,9 +268,7 @@ class Timeline:
     def sleep(self, dur_s: float, *, power_W: float = 0.0,
               t0: Optional[float] = None, advance: bool = True) -> float:
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_SLEEP] += 1
-        elif self.columnar:
+        if self.columnar:
             self._seq.append(_SLEEP)
             c = self._cols[_SLEEP]
             c[0].append(at)
@@ -304,9 +278,7 @@ class Timeline:
             self._events.append(ClusterSleep(at, dur_s, power_W))
         self._span_s["ClusterSleep", None] += dur_s
         if advance:
-            if self.aggregate_only:
-                self._counts[_SAMPLE] += 1
-            elif self.columnar:
+            if self.columnar:
                 self._seq.append(_SAMPLE)
                 c = self._cols[_SAMPLE]
                 c[0].append(at)
@@ -331,9 +303,7 @@ class Timeline:
         concurrent bursts carry no energy of their own."""
         nbytes = int(nbytes)
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_C2C] += 1
-        elif self.columnar:
+        if self.columnar:
             self._seq.append(_C2C)
             c = self._cols[_C2C]
             c[0].append(at)
@@ -348,9 +318,7 @@ class Timeline:
         self.c2c_bytes += nbytes
         if advance:
             if power_W:
-                if self.aggregate_only:
-                    self._counts[_SAMPLE] += 1
-                elif self.columnar:
+                if self.columnar:
                     self._seq.append(_SAMPLE)
                     c = self._cols[_SAMPLE]
                     c[0].append(self.now)
@@ -365,9 +333,7 @@ class Timeline:
               t0: Optional[float] = None) -> None:
         n = int(n)
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_TOKEN] += 1
-        elif self.columnar:
+        if self.columnar:
             self._seq.append(_TOKEN)
             c = self._cols[_TOKEN]
             c[0].append(at)
@@ -388,9 +354,7 @@ class Timeline:
         if not b:
             return
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_TOKEN] += b
-        elif self.columnar:
+        if self.columnar:
             self._seq.extend([_TOKEN] * b)
             c = self._cols[_TOKEN]
             c[0].extend([at] * b)
@@ -405,9 +369,7 @@ class Timeline:
                   t0: Optional[float] = None) -> None:
         """Concurrent instant: this node crashed (fault injection)."""
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_FAIL] += 1
-        elif self.columnar:
+        if self.columnar:
             self._seq.append(_FAIL)
             c = self._cols[_FAIL]
             c[0].append(at)
@@ -419,9 +381,7 @@ class Timeline:
                      t0: Optional[float] = None) -> None:
         """Concurrent instant: this node rejoined after a crash."""
         at = self.now if t0 is None else t0
-        if self.aggregate_only:
-            self._counts[_RECOVER] += 1
-        elif self.columnar:
+        if self.columnar:
             self._seq.append(_RECOVER)
             c = self._cols[_RECOVER]
             c[0].append(at)
@@ -431,9 +391,6 @@ class Timeline:
             self._events.append(NodeRecover(at, node, downtime_s))
 
     def sample(self, power_W: float) -> None:
-        if self.aggregate_only:
-            self._counts[_SAMPLE] += 1
-            return
         if self.columnar:
             self._seq.append(_SAMPLE)
             c = self._cols[_SAMPLE]
@@ -443,17 +400,9 @@ class Timeline:
             self._events.append(EnergySample(self.now, power_W))
 
     # -- event materialization ----------------------------------------
-    def _no_events(self) -> RuntimeError:
-        return RuntimeError(
-            "aggregate-only timeline stores no events; use the running "
-            "aggregates (cycles/span_seconds/count) or record with "
-            "aggregate_only=False")
-
     @property
     def n_events(self) -> int:
         """Event count without materializing anything — O(1)."""
-        if self.aggregate_only:
-            return sum(self._counts)
         return len(self._seq) if self.columnar else len(self._events)
 
     @property
@@ -461,8 +410,6 @@ class Timeline:
         """The dataclass event stream.  In columnar mode this is a lazy,
         incrementally extended materialization cache: appends after a
         read only materialize the new tail on the next read."""
-        if self.aggregate_only:
-            raise self._no_events()
         if not self.columnar:
             return self._events
         if len(self._mat) < len(self._seq):
@@ -479,8 +426,6 @@ class Timeline:
         """Yield events one at a time WITHOUT caching a materialized list
         (columnar mode) — the streaming export path for million-event
         traces."""
-        if self.aggregate_only:
-            raise self._no_events()
         if not self.columnar:
             yield from self._events
             return
@@ -512,8 +457,6 @@ class Timeline:
         fields = self._FIELDS[name]
         if field not in fields:
             raise KeyError(f"{name} has no field {field!r}")
-        if self.aggregate_only:
-            raise self._no_events()
         if self.columnar:
             return list(self._cols[self._CIDS[name]][fields.index(field)])
         return [getattr(e, field) for e in self._events
@@ -537,8 +480,6 @@ class Timeline:
              "NodeFail": _FAIL, "NodeRecover": _RECOVER}
 
     def count(self, cls: Type) -> int:
-        if self.aggregate_only:
-            return self._counts[self._CIDS[cls.__name__]]
         if self.columnar:
             return len(self._cols[self._CIDS[cls.__name__]][0])
         return sum(1 for e in self._events if isinstance(e, cls))
@@ -555,8 +496,6 @@ class Timeline:
 
     def power_trace(self) -> List[Tuple[float, float]]:
         """(t, W) steps from the EnergySample stream."""
-        if self.aggregate_only:
-            raise self._no_events()
         if self.columnar:
             t0s, ws = self._cols[_SAMPLE]
             return list(zip(t0s, ws))
@@ -673,544 +612,3 @@ def merge_chrome_traces(named_timelines) -> Dict:
     for pid, (name, tl) in enumerate(named_timelines):
         events.extend(tl.iter_chrome_events(process_name=name, pid=pid))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-# ---------------------------------------------------------------------------
-# Cell-major aggregate block (the sweep engine's 2D recorder)
-# ---------------------------------------------------------------------------
-
-class SweepAggregates:
-    """The running aggregates of N timelines as cell-major numpy arrays.
-
-    One row of scalars per cell — the exact accumulator set an
-    aggregate-only :class:`Timeline` maintains for the serving decode
-    loop — so a vectorized round update::
-
-        agg.now[idx] += dt; agg.energy_J[idx] += dt * power; ...
-
-    performs, per cell, the same IEEE-754 float64 multiply-adds in the
-    same order as N scalar timelines appending the same spans.  The
-    vector axis is *cells*: lanes never mix, so every cell's float
-    accumulation stays bit-identical to its scalar run.
-
-    ``sync_in(i, tl)`` snapshots one cell's timeline into row ``i`` when
-    that cell enters the vectorized path; ``sync_out(i, tl)`` writes the
-    row back before the cell's scalar engine resumes (or reports).  Only
-    the accumulators the vectorized decode round can touch are mirrored;
-    everything else (prefill spans, sleeps, wakes, cycle sums) mutates
-    exclusively on the scalar side and needs no mirror.
-    """
-
-    _SPAN_KEYS = (("ComputeSpan", None), ("ComputeSpan", "decode"),
-                  ("C2CTransfer", None), ("ComputeSpan", "prefill"),
-                  ("ClusterWake", None))
-
-    def __init__(self, n_cells: int):
-        self.n_cells = n_cells
-        self.now = np.zeros(n_cells)
-        self.busy_s = np.zeros(n_cells)
-        self.energy_J = np.zeros(n_cells)
-        self.occupancy_s = np.zeros(n_cells)
-        self.tokens = np.zeros(n_cells, dtype=np.int64)
-        self.c2c_bytes = np.zeros(n_cells, dtype=np.int64)
-        # per-(class, kind) running span sums, one lane per tracked key
-        self.span_compute = np.zeros(n_cells)
-        self.span_decode = np.zeros(n_cells)
-        self.span_c2c = np.zeros(n_cells)
-        self.span_prefill = np.zeros(n_cells)
-        self.span_wake = np.zeros(n_cells)
-        self.cyc_wake = np.zeros(n_cells, dtype=np.int64)
-        # aggregate-only event counts kept exact during vector rounds
-        self.n_compute = np.zeros(n_cells, dtype=np.int64)
-        self.n_sample = np.zeros(n_cells, dtype=np.int64)
-        self.n_c2c = np.zeros(n_cells, dtype=np.int64)
-        self.n_token = np.zeros(n_cells, dtype=np.int64)
-        self.n_wake = np.zeros(n_cells, dtype=np.int64)
-
-    def sync_in(self, i: int, tl: Timeline) -> None:
-        self.now[i] = tl.now
-        self.busy_s[i] = tl.busy_s
-        self.energy_J[i] = tl.energy_J
-        self.occupancy_s[i] = tl.occupancy_s
-        self.tokens[i] = tl.tokens
-        self.c2c_bytes[i] = tl.c2c_bytes
-        span = tl._span_s
-        self.span_compute[i] = span.get(self._SPAN_KEYS[0], 0.0)
-        self.span_decode[i] = span.get(self._SPAN_KEYS[1], 0.0)
-        self.span_c2c[i] = span.get(self._SPAN_KEYS[2], 0.0)
-        self.span_prefill[i] = span.get(self._SPAN_KEYS[3], 0.0)
-        self.span_wake[i] = span.get(self._SPAN_KEYS[4], 0.0)
-        self.cyc_wake[i] = tl._cycles.get(self._SPAN_KEYS[4], 0)
-        if tl.aggregate_only:
-            cnt = tl._counts
-            self.n_compute[i] = cnt[_COMPUTE]
-            self.n_sample[i] = cnt[_SAMPLE]
-            self.n_c2c[i] = cnt[_C2C]
-            self.n_token[i] = cnt[_TOKEN]
-            self.n_wake[i] = cnt[_WAKE]
-
-    def sync_out(self, i: int, tl: Timeline) -> None:
-        tl.now = float(self.now[i])
-        tl.busy_s = float(self.busy_s[i])
-        tl.energy_J = float(self.energy_J[i])
-        tl.occupancy_s = float(self.occupancy_s[i])
-        tl.tokens = int(self.tokens[i])
-        tl.c2c_bytes = int(self.c2c_bytes[i])
-        span = tl._span_s
-        span[self._SPAN_KEYS[0]] = float(self.span_compute[i])
-        span[self._SPAN_KEYS[1]] = float(self.span_decode[i])
-        span[self._SPAN_KEYS[2]] = float(self.span_c2c[i])
-        # prefill/wake lanes: only written when they carry anything (or
-        # the key already exists) so a decode-only sweep does not grow
-        # the span dict's key set relative to its scalar run
-        for key, col in ((self._SPAN_KEYS[3], self.span_prefill),
-                         (self._SPAN_KEYS[4], self.span_wake)):
-            v = float(col[i])
-            if v or key in span:
-                span[key] = v
-        cw = int(self.cyc_wake[i])
-        if cw or self._SPAN_KEYS[4] in tl._cycles:
-            tl._cycles[self._SPAN_KEYS[4]] = cw
-        if tl.aggregate_only:
-            cnt = tl._counts
-            cnt[_COMPUTE] = int(self.n_compute[i])
-            cnt[_SAMPLE] = int(self.n_sample[i])
-            cnt[_C2C] = int(self.n_c2c[i])
-            cnt[_TOKEN] = int(self.n_token[i])
-            cnt[_WAKE] = int(self.n_wake[i])
-
-    def sync_in_many(self, idx: np.ndarray, tls: Sequence[Timeline]) -> None:
-        """Batched :meth:`sync_in` — one fancy-indexed scatter per column
-        instead of per-lane scalar writes.  All ``tls`` must be
-        aggregate-only recorders (the sweep engine's only mode)."""
-        K0, K1, K2, K3, K4 = self._SPAN_KEYS
-        f = np.array([(tl.now, tl.busy_s, tl.energy_J, tl.occupancy_s,
-                       tl._span_s.get(K0, 0.0), tl._span_s.get(K1, 0.0),
-                       tl._span_s.get(K2, 0.0), tl._span_s.get(K3, 0.0),
-                       tl._span_s.get(K4, 0.0)) for tl in tls])
-        self.now[idx] = f[:, 0]
-        self.busy_s[idx] = f[:, 1]
-        self.energy_J[idx] = f[:, 2]
-        self.occupancy_s[idx] = f[:, 3]
-        self.span_compute[idx] = f[:, 4]
-        self.span_decode[idx] = f[:, 5]
-        self.span_c2c[idx] = f[:, 6]
-        self.span_prefill[idx] = f[:, 7]
-        self.span_wake[idx] = f[:, 8]
-        g = np.array([(tl.tokens, tl.c2c_bytes, tl._cycles.get(K4, 0),
-                       tl._counts[_COMPUTE], tl._counts[_SAMPLE],
-                       tl._counts[_C2C], tl._counts[_TOKEN],
-                       tl._counts[_WAKE]) for tl in tls], dtype=np.int64)
-        self.tokens[idx] = g[:, 0]
-        self.c2c_bytes[idx] = g[:, 1]
-        self.cyc_wake[idx] = g[:, 2]
-        self.n_compute[idx] = g[:, 3]
-        self.n_sample[idx] = g[:, 4]
-        self.n_c2c[idx] = g[:, 5]
-        self.n_token[idx] = g[:, 6]
-        self.n_wake[idx] = g[:, 7]
-
-    def sync_out_many(self, idx: np.ndarray, tls: Sequence[Timeline]) -> None:
-        """Batched :meth:`sync_out`: gather every column once, then per-
-        timeline attribute stores (aggregate-only recorders required)."""
-        K0, K1, K2, K3, K4 = self._SPAN_KEYS
-        now, busy, en, occ = (self.now[idx], self.busy_s[idx],
-                              self.energy_J[idx], self.occupancy_s[idx])
-        tok, cbytes = self.tokens[idx], self.c2c_bytes[idx]
-        sc, sd, s2 = (self.span_compute[idx], self.span_decode[idx],
-                      self.span_c2c[idx])
-        sp, sw, cw = (self.span_prefill[idx], self.span_wake[idx],
-                      self.cyc_wake[idx])
-        nc, ns, n2, nt, nw = (self.n_compute[idx], self.n_sample[idx],
-                              self.n_c2c[idx], self.n_token[idx],
-                              self.n_wake[idx])
-        for k, tl in enumerate(tls):
-            tl.now = float(now[k])
-            tl.busy_s = float(busy[k])
-            tl.energy_J = float(en[k])
-            tl.occupancy_s = float(occ[k])
-            tl.tokens = int(tok[k])
-            tl.c2c_bytes = int(cbytes[k])
-            span = tl._span_s
-            span[K0] = float(sc[k])
-            span[K1] = float(sd[k])
-            span[K2] = float(s2[k])
-            v = float(sp[k])
-            if v or K3 in span:
-                span[K3] = v
-            v = float(sw[k])
-            if v or K4 in span:
-                span[K4] = v
-            c = int(cw[k])
-            if c or K4 in tl._cycles:
-                tl._cycles[K4] = c
-            cnt = tl._counts
-            cnt[_COMPUTE] = int(nc[k])
-            cnt[_SAMPLE] = int(ns[k])
-            cnt[_C2C] = int(n2[k])
-            cnt[_TOKEN] = int(nt[k])
-            cnt[_WAKE] = int(nw[k])
-
-    def decode_round(self, idx: np.ndarray, dt: np.ndarray,
-                     power_W: np.ndarray, batch: np.ndarray,
-                     burst_bytes: np.ndarray, burst_dur: np.ndarray,
-                     fetch_bytes: np.ndarray, fetch_dur: np.ndarray) -> None:
-        """One batched decode round for the cells in ``idx`` — the
-        vectorized equivalent of the scalar engine's per-round timeline
-        appends, in the scalar append order:
-
-          1. ``compute(dt, kind="decode", power_W, batch)``
-          2. concurrent decode C2C burst (``burst_bytes`` over
-             ``burst_dur``)
-          3. advancing kv-fetch C2C at chip power (``fetch_bytes`` over
-             ``fetch_dur``; zero for non-paged cells — adding 0.0 /
-             +0 is bit-neutral on every accumulator, matching the scalar
-             engine *skipping* those appends)
-          4. one `TokenEmit` per resident
-
-        Each numbered update is a separate elementwise op, so within a
-        lane the float adds hit each accumulator in the scalar order.
-        """
-        # 1. decode ComputeSpan (+ its auto power sample)
-        self.span_compute[idx] += dt
-        self.span_decode[idx] += dt
-        self.busy_s[idx] += dt
-        self.energy_J[idx] += dt * power_W
-        self.occupancy_s[idx] += dt * batch
-        self.now[idx] += dt
-        self.n_compute[idx] += 1
-        self.n_sample[idx] += 1
-        # 2. concurrent decode burst
-        self.span_c2c[idx] += burst_dur
-        self.c2c_bytes[idx] += burst_bytes
-        self.n_c2c[idx] += (burst_bytes > 0)
-        # 3. advancing kv fetch at chip power
-        self.span_c2c[idx] += fetch_dur
-        self.c2c_bytes[idx] += fetch_bytes
-        self.energy_J[idx] += fetch_dur * power_W
-        self.busy_s[idx] += fetch_dur
-        self.now[idx] += fetch_dur
-        has_fetch = fetch_bytes > 0
-        self.n_c2c[idx] += has_fetch
-        self.n_sample[idx] += has_fetch & (power_W > 0)
-        # 4. token emits
-        self.tokens[idx] += batch
-        self.n_token[idx] += batch
-
-    def decode_burst(self, idx: np.ndarray, h: np.ndarray, dt: np.ndarray,
-                     power_W: np.ndarray, batch: np.ndarray,
-                     burst_bytes: np.ndarray, burst_dur: np.ndarray,
-                     fetch_bytes: np.ndarray, fetch_dur: np.ndarray,
-                     next_arrival: np.ndarray,
-                     wake_dt: Optional[np.ndarray] = None,
-                     wake_cyc: Optional[np.ndarray] = None,
-                     risk_eta: Optional[np.ndarray] = None,
-                     risk_bound: Optional[np.ndarray] = None) -> np.ndarray:
-        """Apply up to ``h[k]`` consecutive decode rounds to each lane
-        ``idx[k]`` in one shot — bit-identical to calling
-        :meth:`decode_round` that many times per lane, because
-        ``np.add.accumulate`` is a strict sequential left fold (no
-        pairwise regrouping) and each accumulator's fold starts from its
-        current value (row 0 of the increment matrix).
-
-        ``dt`` is the per-round compute duration, shape ``(H, n)`` with
-        ``H >= h.max()``; row ``j`` prices round ``j+1`` of the burst.
-        Rows beyond a lane's ``h`` are ignored (each lane's result is
-        gathered at its own prefix position, so garbage rows past the
-        horizon never contribute).
-
-        Rounds are additionally truncated at the lane's next request
-        arrival: the scalar engine admits (and leaves pure decode) once
-        its clock reaches ``next_arrival``, so a burst must not price
-        rounds past that point.  Returns the per-lane round counts
-        actually applied (``>= 1`` — callers guarantee no arrival is due
-        at burst entry).
-
-        ``wake_dt`` / ``wake_cyc`` (dynamic CCPG): a per-lane constant
-        ``ClusterWake`` walk replayed *before* each round's compute —
-        ``busy/energy/now`` see an extra add per round in the scalar
-        order, ``("ClusterWake", None)`` span/cycles and the wake/sample
-        counts advance for lanes with ``wake_dt > 0``.  Zero-``wake_dt``
-        lanes are bit-neutral.
-
-        ``risk_eta`` / ``risk_bound`` (TTFT deadlines): rounds are also
-        truncated once the lane's clock would put its queue head at
-        deadline risk — round ``j`` runs only while
-        ``clock_before_j + risk_eta < risk_bound`` (the scalar engine's
-        ``clock + prefill_eta >= arrival + deadline_ttft`` at-risk test,
-        same float expression).  Pass ``risk_eta = 0.0`` /
-        ``risk_bound = inf`` for unconstrained lanes.
-        """
-        n = int(idx.size)
-        H = int(h.max())
-        dt = dt[:H]
-        lanes = np.arange(n)
-        if wake_dt is not None and wake_dt.any():
-            return self._decode_burst_wake(
-                idx, h, dt, power_W, batch, burst_bytes, burst_dur,
-                fetch_bytes, fetch_dur, next_arrival, wake_dt, wake_cyc,
-                risk_eta, risk_bound)
-        if not fetch_bytes.any():
-            # Fetch-free fast path: every accumulator sees exactly one
-            # add per round (the fetch adds would all be `x + 0.0`,
-            # which is bit-neutral on the non-negative accumulators but
-            # doubles the fold depth) — fold all seven in one matrix.
-            inc = np.empty((H + 1, 7 * n))
-            starts = (self.now, self.busy_s, self.energy_J,
-                      self.span_c2c, self.span_compute, self.span_decode,
-                      self.occupancy_s)
-            for k, a in enumerate(starts):
-                inc[0, k * n:(k + 1) * n] = a[idx]
-            inc[1:, 0 * n:1 * n] = dt
-            inc[1:, 1 * n:2 * n] = dt
-            inc[1:, 2 * n:3 * n] = dt * power_W
-            inc[1:, 3 * n:4 * n] = burst_dur
-            inc[1:, 4 * n:5 * n] = dt
-            inc[1:, 5 * n:6 * n] = dt
-            inc[1:, 6 * n:7 * n] = dt * batch
-            acc = np.add.accumulate(inc, axis=0)
-            # Round j+1 (0-based j) runs only while the clock *before*
-            # it — acc row j of the `now` block — is short of the
-            # arrival; monotone, so the count is the prefix length.
-            j = np.arange(H)[:, None]
-            clock = acc[:H, :n]
-            ok = clock < next_arrival
-            if risk_eta is not None:
-                ok &= (clock + risk_eta) < risk_bound
-            h = (ok & (j < h)).sum(axis=0)
-            for k, a in enumerate(starts):
-                a[idx] = acc[h, k * n + lanes]
-            self.tokens[idx] += batch * h
-            self.c2c_bytes[idx] += burst_bytes * h
-            self.n_compute[idx] += h
-            self.n_token[idx] += batch * h
-            self.n_c2c[idx] += (burst_bytes > 0) * h
-            self.n_sample[idx] += h
-            return h
-        # Clock prefix first: interleave (dt, fetch_dur) per round — the
-        # scalar order is now += dt then now += fetch_dur — with the
-        # current clock in row 0 so the fold seeds correctly.
-        incN = np.empty((2 * H + 1, n))
-        incN[0] = self.now[idx]
-        incN[1::2] = dt
-        incN[2::2] = fetch_dur
-        accN = np.add.accumulate(incN, axis=0)
-        # Round j+1 (0-based j) runs only while the clock *before* it —
-        # accN[2j] — is still short of the arrival; the predicate is
-        # monotone (clock never decreases) so the count is the prefix
-        # length.
-        j = np.arange(H)[:, None]
-        clock = accN[0:2 * H:2]
-        ok = clock < next_arrival
-        if risk_eta is not None:
-            ok &= (clock + risk_eta) < risk_bound
-        h = (ok & (j < h)).sum(axis=0)
-        r2 = 2 * h
-        self.now[idx] = accN[r2, lanes]
-        # busy / energy / span_c2c also see two adds per round, with
-        # per-accumulator increments; fold all three in one accumulate.
-        incB = np.empty((2 * H + 1, 3 * n))
-        incB[0, :n] = self.busy_s[idx]
-        incB[0, n:2 * n] = self.energy_J[idx]
-        incB[0, 2 * n:] = self.span_c2c[idx]
-        incB[1::2, :n] = dt
-        incB[2::2, :n] = fetch_dur
-        incB[1::2, n:2 * n] = dt * power_W
-        incB[2::2, n:2 * n] = fetch_dur * power_W
-        incB[1::2, 2 * n:] = burst_dur
-        incB[2::2, 2 * n:] = fetch_dur
-        accB = np.add.accumulate(incB, axis=0)
-        self.busy_s[idx] = accB[r2, lanes]
-        self.energy_J[idx] = accB[r2, n + lanes]
-        self.span_c2c[idx] = accB[r2, 2 * n + lanes]
-        # One-add-per-round accumulators: span_compute / span_decode
-        # (same increments, different starts) and occupancy.
-        incS = np.empty((H + 1, 3 * n))
-        incS[0, :n] = self.span_compute[idx]
-        incS[0, n:2 * n] = self.span_decode[idx]
-        incS[0, 2 * n:] = self.occupancy_s[idx]
-        incS[1:, :n] = dt
-        incS[1:, n:2 * n] = dt
-        incS[1:, 2 * n:] = dt * batch
-        accS = np.add.accumulate(incS, axis=0)
-        self.span_compute[idx] = accS[h, lanes]
-        self.span_decode[idx] = accS[h, lanes + n]
-        self.occupancy_s[idx] = accS[h, lanes + 2 * n]
-        # Integer counters are associative — closed form is exact.
-        self.tokens[idx] += batch * h
-        self.c2c_bytes[idx] += (burst_bytes + fetch_bytes) * h
-        self.n_compute[idx] += h
-        self.n_token[idx] += batch * h
-        self.n_c2c[idx] += ((burst_bytes > 0).astype(np.int64)
-                            + (fetch_bytes > 0)) * h
-        self.n_sample[idx] += h + ((fetch_bytes > 0) & (power_W > 0)) * h
-        return h
-
-    def _decode_burst_wake(self, idx, h, dt, power_W, batch,
-                           burst_bytes, burst_dur, fetch_bytes, fetch_dur,
-                           next_arrival, wake_dt, wake_cyc,
-                           risk_eta, risk_bound) -> np.ndarray:
-        """Dynamic-CCPG decode burst: each round replays the scalar
-        engine's ``ClusterWake`` walk, then compute, then the (possibly
-        zero) kv fetch — ``now/busy/energy`` fold three adds per round
-        in that order.  ``dt`` is already trimmed to ``(H, n)``.
-        Zero-``wake_dt`` lanes add ``x + 0.0`` on non-negative
-        accumulators (bit-neutral) and are excluded from the wake
-        span/cycle/count columns.
-        """
-        n = int(idx.size)
-        H = dt.shape[0]
-        lanes = np.arange(n)
-        # now / busy / energy: wake, compute, fetch adds per round.
-        inc3 = np.empty((3 * H + 1, 3 * n))
-        inc3[0, :n] = self.now[idx]
-        inc3[0, n:2 * n] = self.busy_s[idx]
-        inc3[0, 2 * n:] = self.energy_J[idx]
-        inc3[1::3, :n] = wake_dt
-        inc3[2::3, :n] = dt
-        inc3[3::3, :n] = fetch_dur
-        inc3[1::3, n:2 * n] = wake_dt
-        inc3[2::3, n:2 * n] = dt
-        inc3[3::3, n:2 * n] = fetch_dur
-        inc3[1::3, 2 * n:] = wake_dt * power_W
-        inc3[2::3, 2 * n:] = dt * power_W
-        inc3[3::3, 2 * n:] = fetch_dur * power_W
-        acc3 = np.add.accumulate(inc3, axis=0)
-        j = np.arange(H)[:, None]
-        clock = acc3[0:3 * H:3, :n]
-        ok = clock < next_arrival
-        if risk_eta is not None:
-            ok &= (clock + risk_eta) < risk_bound
-        h = (ok & (j < h)).sum(axis=0)
-        r3 = 3 * h
-        self.now[idx] = acc3[r3, lanes]
-        self.busy_s[idx] = acc3[r3, n + lanes]
-        self.energy_J[idx] = acc3[r3, 2 * n + lanes]
-        # span_c2c: two adds per round (decode burst, then fetch).
-        inc2 = np.empty((2 * H + 1, n))
-        inc2[0] = self.span_c2c[idx]
-        inc2[1::2] = burst_dur
-        inc2[2::2] = fetch_dur
-        self.span_c2c[idx] = np.add.accumulate(inc2, axis=0)[2 * h, lanes]
-        # One add per round: compute/decode spans, occupancy, wake span.
-        incS = np.empty((H + 1, 4 * n))
-        incS[0, :n] = self.span_compute[idx]
-        incS[0, n:2 * n] = self.span_decode[idx]
-        incS[0, 2 * n:3 * n] = self.occupancy_s[idx]
-        incS[0, 3 * n:] = self.span_wake[idx]
-        incS[1:, :n] = dt
-        incS[1:, n:2 * n] = dt
-        incS[1:, 2 * n:3 * n] = dt * batch
-        incS[1:, 3 * n:] = wake_dt
-        accS = np.add.accumulate(incS, axis=0)
-        self.span_compute[idx] = accS[h, lanes]
-        self.span_decode[idx] = accS[h, lanes + n]
-        self.occupancy_s[idx] = accS[h, lanes + 2 * n]
-        self.span_wake[idx] = accS[h, lanes + 3 * n]
-        # Integer counters are associative — closed form is exact.
-        woke = wake_dt > 0
-        self.tokens[idx] += batch * h
-        self.c2c_bytes[idx] += (burst_bytes + fetch_bytes) * h
-        self.cyc_wake[idx] += woke * wake_cyc * h
-        self.n_wake[idx] += woke * h
-        self.n_compute[idx] += h
-        self.n_token[idx] += batch * h
-        self.n_c2c[idx] += ((burst_bytes > 0).astype(np.int64)
-                            + (fetch_bytes > 0)) * h
-        self.n_sample[idx] += (h + woke * h
-                               + ((fetch_bytes > 0) & (power_W > 0)) * h)
-        return h
-
-    def prefill_burst(self, idx: np.ndarray, h: np.ndarray, dt: np.ndarray,
-                      power_W: np.ndarray, burst_bytes: np.ndarray,
-                      burst_dur: np.ndarray, next_arrival: np.ndarray,
-                      wake_dt: Optional[np.ndarray] = None,
-                      wake_cyc: Optional[np.ndarray] = None) -> np.ndarray:
-        """Apply up to ``h[k]`` consecutive *full-cap prefill chunk*
-        steps to each lane ``idx[k]`` — the vectorized equivalent of the
-        scalar engine's per-chunk appends while a partial prefill cruises
-        alone (no residents, no due arrival):
-
-          1. optional dynamic-CCPG ``ClusterWake`` walk (``wake_dt``)
-          2. ``compute(dt_j, kind="prefill", power_W, batch=1)``
-          3. concurrent prefill C2C (``burst_bytes`` over ``burst_dur``;
-             non-advancing, zero bytes skip the scalar append —
-             bit-neutral here)
-
-        ``dt`` has shape ``(H, n)``: row ``j`` prices chunk ``j+1``
-        (context grows by the chunk cap each step).  Chunks truncate at
-        the lane's next arrival, exactly like :meth:`decode_burst`.
-        Returns the per-lane chunk counts applied.
-        """
-        n = int(idx.size)
-        H = int(h.max())
-        dt = dt[:H]
-        lanes = np.arange(n)
-        j = np.arange(H)[:, None]
-        if wake_dt is not None and wake_dt.any():
-            # now / busy / energy: wake + compute adds per round.
-            inc2 = np.empty((2 * H + 1, 3 * n))
-            inc2[0, :n] = self.now[idx]
-            inc2[0, n:2 * n] = self.busy_s[idx]
-            inc2[0, 2 * n:] = self.energy_J[idx]
-            inc2[1::2, :n] = wake_dt
-            inc2[2::2, :n] = dt
-            inc2[1::2, n:2 * n] = wake_dt
-            inc2[2::2, n:2 * n] = dt
-            inc2[1::2, 2 * n:] = wake_dt * power_W
-            inc2[2::2, 2 * n:] = dt * power_W
-            acc2 = np.add.accumulate(inc2, axis=0)
-            h = ((acc2[0:2 * H:2, :n] < next_arrival)
-                 & (j < h)).sum(axis=0)
-            r2 = 2 * h
-            self.now[idx] = acc2[r2, lanes]
-            self.busy_s[idx] = acc2[r2, n + lanes]
-            self.energy_J[idx] = acc2[r2, 2 * n + lanes]
-            # One add per round: spans, occupancy (batch == 1), wake.
-            incS = np.empty((H + 1, 5 * n))
-            incS[0, :n] = self.span_compute[idx]
-            incS[0, n:2 * n] = self.span_prefill[idx]
-            incS[0, 2 * n:3 * n] = self.span_c2c[idx]
-            incS[0, 3 * n:4 * n] = self.occupancy_s[idx]
-            incS[0, 4 * n:] = self.span_wake[idx]
-            incS[1:, :n] = dt
-            incS[1:, n:2 * n] = dt
-            incS[1:, 2 * n:3 * n] = burst_dur
-            incS[1:, 3 * n:4 * n] = dt
-            incS[1:, 4 * n:] = wake_dt
-            accS = np.add.accumulate(incS, axis=0)
-            self.span_compute[idx] = accS[h, lanes]
-            self.span_prefill[idx] = accS[h, lanes + n]
-            self.span_c2c[idx] = accS[h, lanes + 2 * n]
-            self.occupancy_s[idx] = accS[h, lanes + 3 * n]
-            self.span_wake[idx] = accS[h, lanes + 4 * n]
-            woke = wake_dt > 0
-            self.cyc_wake[idx] += woke * wake_cyc * h
-            self.n_wake[idx] += woke * h
-            self.c2c_bytes[idx] += burst_bytes * h
-            self.n_compute[idx] += h
-            self.n_sample[idx] += h + woke * h
-            self.n_c2c[idx] += (burst_bytes > 0) * h
-            return h
-        # Wake-free: one add per round on every accumulator.
-        inc = np.empty((H + 1, 7 * n))
-        starts = (self.now, self.busy_s, self.energy_J, self.span_c2c,
-                  self.span_compute, self.span_prefill, self.occupancy_s)
-        for k, a in enumerate(starts):
-            inc[0, k * n:(k + 1) * n] = a[idx]
-        inc[1:, 0 * n:1 * n] = dt
-        inc[1:, 1 * n:2 * n] = dt
-        inc[1:, 2 * n:3 * n] = dt * power_W
-        inc[1:, 3 * n:4 * n] = burst_dur
-        inc[1:, 4 * n:5 * n] = dt
-        inc[1:, 5 * n:6 * n] = dt
-        inc[1:, 6 * n:7 * n] = dt  # occupancy: batch == 1 during cruise
-        acc = np.add.accumulate(inc, axis=0)
-        h = ((acc[:H, :n] < next_arrival) & (j < h)).sum(axis=0)
-        for k, a in enumerate(starts):
-            a[idx] = acc[h, k * n + lanes]
-        self.c2c_bytes[idx] += burst_bytes * h
-        self.n_compute[idx] += h
-        self.n_sample[idx] += h
-        self.n_c2c[idx] += (burst_bytes > 0) * h
-        return h

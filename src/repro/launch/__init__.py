@@ -2,8 +2,8 @@
 
 Submodules are imported lazily by callers (several pull in JAX at import
 time); the analytic serving stack (``scheduler``, ``serving_engine``,
-``sweep_engine``, ``fleet``) stays JAX-free so traffic simulations run
-instantly on any host.
+``fleet_engine``) stays JAX-free so traffic simulations run instantly on
+any host.
 
 This package is also the PUBLIC serving API (ISSUE 9): one documented
 facade over the three execution tiers, so examples and benchmarks stop
@@ -25,7 +25,7 @@ importing module internals —
     (cluster aggregate);
   * entry points —
       serve(cfg, trace, ...)   one engine, one trace  -> ServingReport
-      sweep(cells)             vectorized cell grid   -> [SweepResult]
+      sweep(cells)             one engine per cell    -> [SweepResult]
       fleet(cfg, trace, ...)   multi-node disaggregated cluster
                                                       -> FleetReport
 
@@ -35,17 +35,18 @@ repro.launch`` cheap and JAX-free.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+import copy
+from typing import List, Optional, Sequence
 
 from repro.launch.config import (FaultConfig, FleetConfig, LinkFault,
                                  NodeFault, ServingConfig, WakeFault)
-from repro.launch.serving_engine import (ServingReport, Trace,
-                                         TrackedRequest, poisson_trace,
-                                         replay_trace)
+from repro.launch.serving_engine import (ServingReport, SweepCell,
+                                         SweepResult, Trace, TrackedRequest,
+                                         poisson_trace, replay_trace)
 
 __all__ = [
     "FaultConfig", "FleetConfig", "LinkFault", "NodeFault",
-    "ServingConfig", "ServingReport", "Trace",
+    "ServingConfig", "ServingReport", "SweepCell", "SweepResult", "Trace",
     "TrackedRequest", "WakeFault", "poisson_trace", "replay_trace",
     "serve", "sweep", "fleet",
 ]
@@ -63,12 +64,26 @@ def serve(cfg, trace: Sequence[TrackedRequest], *,
     return eng.run(trace)
 
 
-def sweep(cells):
-    """Run a grid of `sweep_engine.SweepCell`s through one vectorized
-    lockstep pass; results in cell order, each byte-identical to a
-    per-cell scalar engine run."""
-    from repro.launch.sweep_engine import sweep_serve
-    return sweep_serve(cells)
+def sweep(cells: Sequence[SweepCell]) -> List[SweepResult]:
+    """Run each :class:`SweepCell` through its own fresh
+    :class:`ContinuousBatchingEngine`, in cell order.  Cells with
+    ``sim=None`` share one default simulator.  Each cell serves a copy
+    of its trace's requests, so a trace shared by several cells is left
+    as it was."""
+    from repro.core.simulator import PicnicSimulator
+    from repro.launch.serving_engine import ContinuousBatchingEngine
+    default_sim = None
+    results = []
+    for cell in cells:
+        sim = cell.sim
+        if sim is None:
+            if default_sim is None:
+                default_sim = PicnicSimulator()
+            sim = default_sim
+        eng = ContinuousBatchingEngine(cell.cfg, sim=sim, engine=cell.engine)
+        report = eng.run([copy.copy(r) for r in cell.trace])
+        results.append(SweepResult(cell.key, report, eng.kv_stats))
+    return results
 
 
 def fleet(cfg, trace: Sequence[TrackedRequest], *,

@@ -1,8 +1,8 @@
 """The public serving-configuration schema (ISSUE 9 API redesign).
 
 One keyword-only, versioned config family shared by every serving
-entry point — `ContinuousBatchingEngine` (scalar), `SweepEngine`
-(vectorized grids) and `FleetEngine` (multi-node disaggregation) all
+entry point — `ContinuousBatchingEngine` (one engine, and one per cell
+of a `sweep` grid) and `FleetEngine` (multi-node disaggregation) both
 construct from :class:`ServingConfig`; the fleet layer adds its knobs
 in :class:`FleetConfig`, which *embeds* a ServingConfig per node
 instead of duplicating its fields.
@@ -90,12 +90,6 @@ class ServingConfig:
     # are byte-identical (tests/test_fastpath.py); the toggle exists for
     # the equivalence tests and the microbench before/after measurement.
     columnar_timeline: bool = True
-    # aggregate-only TimelineIR recording (the sweep-engine recorder):
-    # running sums and counts only, NO event stream — reading
-    # `timeline.events` / exporting a trace raises.  Every report-level
-    # aggregate stays byte-identical to the other recorders (same float
-    # adds in the same order); takes precedence over columnar_timeline.
-    aggregate_timeline: bool = False
 
     def to_dict(self) -> Dict:
         d = {"schema": self.SCHEMA_VERSION}

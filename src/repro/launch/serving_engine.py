@@ -350,6 +350,27 @@ class ServingReport:
         return "\n".join(lines)
 
 
+@dataclasses.dataclass
+class SweepCell:
+    """One point of a :func:`repro.launch.sweep` grid: a model config, a
+    trace and the engine settings to serve it with.  Cells with
+    ``sim=None`` share one default `PicnicSimulator`."""
+    key: str
+    cfg: object
+    trace: Sequence[TrackedRequest]
+    engine: ServingConfig = dataclasses.field(
+        default_factory=ServingConfig)
+    sim: Optional[PicnicSimulator] = None
+
+
+@dataclasses.dataclass
+class SweepResult:
+    """One cell's report, with its paged-KV stats (``None`` unpaged)."""
+    key: str
+    report: ServingReport
+    kv_stats: Optional[KVCacheStats]
+
+
 class ContinuousBatchingEngine:
     """Iteration-level continuous batching over the PICNIC cycle model.
 
@@ -374,7 +395,7 @@ class ContinuousBatchingEngine:
         # `is not None` at each site, keeping the single-node float/event
         # sequence byte-identical.
         self.on_finish: Optional[Callable[[TrackedRequest], bool]] = None
-        # `alloc` lets N engines of a sweep grid share one allocation
+        # `alloc` lets the engines of a fleet share one allocation
         # object (allocate_chiplets is deterministic, so sharing changes
         # id()-keyed memo hit rates, never results); default: private.
         self.alloc: ChipletAllocation = (
@@ -400,8 +421,7 @@ class ContinuousBatchingEngine:
         # ALL time/energy accounting lives in the TimelineIR accumulator —
         # the engine appends per-round events and never charges privately
         self.timeline = Timeline(link=self.sim.link,
-                                 columnar=e.columnar_timeline,
-                                 aggregate_only=e.aggregate_timeline)
+                                 columnar=e.columnar_timeline)
         self.queue: Deque[TrackedRequest] = deque()
         self.slots: List[Optional[TrackedRequest]] = [None] * e.max_batch
         # -- SoA mirrors of the slot table (the fast-path state): the
@@ -629,9 +649,7 @@ class ContinuousBatchingEngine:
 
     def _prefill_eta_s(self) -> float:
         """Prefill latency the queue HEAD would pay if admitted now —
-        the horizon the TTFT at-risk test compares against.  Shared with
-        the sweep engine, which freezes it per cruise (the head, and
-        hence the estimate, cannot change between scalar events)."""
+        the horizon the TTFT at-risk test compares against."""
         head = self.queue[0]
         dt, _ = self.sim.prefill_seconds(
             self.cfg, self.alloc, head.prompt_len + head.generated,
@@ -1075,8 +1093,8 @@ class ContinuousBatchingEngine:
     def _prepare_run(self, trace: Sequence[TrackedRequest]
                      ) -> Deque[TrackedRequest]:
         """Reset the engine and the trace's mutable per-run state, verify
-        arrival order, and hand back the pending deque — factored out of
-        :meth:`run` so the sweep engine can drive the step loop itself."""
+        arrival order, and hand back the pending deque (the fleet engine
+        mirrors these steps for its nodes)."""
         self.reset()
         for r in trace:
             # re-running a trace must be idempotent: the resume/recompute
@@ -1113,11 +1131,10 @@ class ContinuousBatchingEngine:
         return self._report(list(trace))
 
     # ------------------------------------------------------------------
-    def _report_inputs(self, requests: List[TrackedRequest]):
-        """Report fields minus the four percentile columns, plus the raw
-        ``(lat, ttft)`` arrays — the sweep engine defers and BATCHES the
-        ``np.percentile`` calls across cells (row-identical to per-cell
-        calls), everything else is cheap scalar arithmetic."""
+    def _report(self, requests: List[TrackedRequest]) -> ServingReport:
+        """Everything here is DERIVED from the timeline integrator: wall
+        clock, busy/idle split, span-integrated chip energy, C2C bytes,
+        token counts, batch occupancy."""
         tl = self.timeline
         done = [r for r in requests if r.finished_at is not None]
         # NaN, not 0.0, when nothing finished: an all-rejected run must
@@ -1139,7 +1156,7 @@ class ContinuousBatchingEngine:
             # include_dram_hub path); guarded so the paging-off default
             # keeps its float sequence byte-identical
             energy += dram_bytes * 8 * E_DRAM_ACCESS
-        fields = dict(
+        return ServingReport(
             n_requests=len(requests),
             finished=len(done),
             rejected=self.rejected,
@@ -1151,6 +1168,10 @@ class ContinuousBatchingEngine:
             tokens_per_s=tl.tokens / wall,
             energy_J=energy,
             tokens_per_J=tl.tokens / max(energy, 1e-12),
+            p50_latency_s=float(np.percentile(lat, 50)),
+            p99_latency_s=float(np.percentile(lat, 99)),
+            p50_ttft_s=float(np.percentile(ttft, 50)),
+            p99_ttft_s=float(np.percentile(ttft, 99)),
             mean_batch_occupancy=(tl.occupancy_s
                                   / max(tl.busy_s, 1e-12)),
             max_queue_depth=max((d for _, d in self.queue_depth),
@@ -1158,20 +1179,6 @@ class ContinuousBatchingEngine:
             queue_depth=self.queue_depth,
             c2c_bytes_total=tl.c2c_bytes,
             ccpg=self.engine.ccpg,
-        )
-        return fields, lat, ttft
-
-    def _report(self, requests: List[TrackedRequest]) -> ServingReport:
-        """Everything here is DERIVED from the timeline integrator: wall
-        clock, busy/idle split, span-integrated chip energy, C2C bytes,
-        token counts, batch occupancy."""
-        fields, lat, ttft = self._report_inputs(requests)
-        return ServingReport(
-            p50_latency_s=float(np.percentile(lat, 50)),
-            p99_latency_s=float(np.percentile(lat, 99)),
-            p50_ttft_s=float(np.percentile(ttft, 50)),
-            p99_ttft_s=float(np.percentile(ttft, 99)),
-            **fields,
         )
 
 

@@ -3,6 +3,7 @@ SoA serving loop and the memoized CycleModel must be BIT-IDENTICAL to
 the reference object path — property-tested on random traces, locked on
 the committed golden, and exercised through every consumer (reports,
 kv_stats, chrome traces, O(1) aggregate queries)."""
+import copy
 import dataclasses
 import json
 from pathlib import Path
@@ -249,6 +250,128 @@ def test_fast_engine_matches_reference_with_deadlines(cfg):
     assert fast.events == ref.events
 
 
+def _kv(cfg, n_blocks, dram_blocks=0, prefix_sharing=False):
+    return KVCacheConfig(n_blocks=n_blocks, block_tokens=16,
+                         dram_blocks=dram_blocks, prefix_sharing=prefix_sharing,
+                         bytes_per_token=kv_bytes_per_token(cfg))
+
+
+_UNSORTED_ROWS = [(0.3, 64, 8), (0.0, 128, 16, 0.05), (0.2, 32, 4),
+                  (0.1, 256, 8), (0.05, 16, 2)]
+
+
+@pytest.mark.parametrize("arch, engine_kw, trace, witness", [
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=4, decode_quantum=1),
+        lambda: poisson_trace(16, 80.0, seed=1, prompt_len=256, max_new=32),
+        lambda rep, kv: rep.finished == 16, id="decode_quantum_1"),
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=4, decode_quantum=16),
+        lambda: poisson_trace(16, 80.0, seed=1, prompt_len=256, max_new=32),
+        lambda rep, kv: rep.max_queue_depth > 8, id="decode_quantum_16"),
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=2, queue_limit=2),
+        lambda: poisson_trace(24, 400.0, seed=2, prompt_len=512, max_new=32),
+        lambda rep, kv: rep.rejected > 0, id="queue_limit_rejects"),
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=4, kv_cache=_kv(c, 12)),
+        lambda: poisson_trace(10, 80.0, seed=3, prompt_len=64, max_new=64),
+        lambda rep, kv: kv.preemptions > 0, id="paged_preempts"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4, ccpg=True, kv_cache=_kv(c, 16, 64)),
+        lambda: poisson_trace(10, 80.0, seed=4, prompt_len=96, max_new=64),
+        lambda rep, kv: kv.spilled_blocks > 0 and kv.dram_read_bytes > 0,
+        id="dram_spills"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4,
+                       kv_cache=_kv(c, 64, prefix_sharing=True)),
+        lambda: poisson_trace(12, 80.0, seed=5, prompt_len=160, max_new=24,
+                              prefix_len=120, prefix_frac=0.8,
+                              prefix_groups=2, deadline_ttft=0.05),
+        lambda rep, kv: kv.cow_forks > 0 and kv.prefix_hits > 0,
+        id="prefix_cow_deadlines"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4, overlap=1.0, kv_cache=_kv(c, 24, 16)),
+        lambda: poisson_trace(10, 60.0, seed=6, prompt_len=128, max_new=48),
+        lambda rep, kv: kv.preemptions > 0, id="overlap_1_paged"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4, ccpg=True, kv_cache=_kv(c, 24, 16)),
+        lambda: poisson_trace(10, 60.0, seed=7, prompt_len=128, max_new=48),
+        lambda rep, kv: kv.spilled_blocks > 0, id="static_ccpg_paged"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4, ccpg=True, dynamic_ccpg=True,
+                       overlap=0.5, kv_cache=_kv(c, 24, 16)),
+        lambda: poisson_trace(10, 60.0, seed=8, prompt_len=128, max_new=48),
+        lambda rep, kv: kv.spilled_blocks > 0,
+        id="dynamic_ccpg_overlap_half_paged"),
+    pytest.param(
+        "llama3.2-1b",
+        lambda c: dict(max_batch=4, chunked_prefill_tokens=64,
+                       kv_cache=_kv(c, 64, prefix_sharing=True)),
+        lambda: poisson_trace(12, 80.0, seed=9, prompt_len=320, max_new=16,
+                              prefix_len=256, prefix_frac=0.8),
+        lambda rep, kv: kv.prefix_hits > 0, id="chunk_64_prefix_sharing"),
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=16),
+        lambda: poisson_trace(48, 2000.0, seed=10, prompt_len=256,
+                              max_new=64, deadline_ttft=0.02),
+        lambda rep, kv: rep.max_queue_depth > 16, id="batch_16_saturated"),
+    pytest.param(
+        "llama3.2-1b", lambda c: dict(max_batch=4),
+        lambda: list(reversed(replay_trace(_UNSORTED_ROWS))),
+        lambda rep, kv: rep.finished == len(_UNSORTED_ROWS),
+        id="replay_unsorted_arrivals"),
+    pytest.param(
+        "llama3-8b",
+        lambda c: dict(max_batch=4, ccpg=True, chunked_prefill_tokens=128),
+        lambda: poisson_trace(8, 20.0, seed=11, prompt_len=256, max_new=32),
+        lambda rep, kv: rep.finished == 8, id="llama3_8b_chunked_ccpg"),
+])
+def test_fast_engine_matches_reference_on_settings(arch, engine_kw, trace,
+                                                   witness):
+    """The fast engine (columnar recorder, memoized cycle model, inline
+    affine decode pricing over a running context sum) against the
+    reference path on settings the cell grids of tests/test_engine_grid.py
+    leave out.  ``witness`` checks that each case reaches the path it
+    names.  The defect each case fails at:
+
+    * decode_quantum_1, decode_quantum_16, queue_limit_rejects,
+      batch_16_saturated, replay_unsorted_arrivals: a deferred finish
+      that releases its slot before syncing the request's final context,
+      so the running context sum drifts;
+    * paged_preempts: a preempted resident's context left in the sum;
+    * dram_spills: the columnar recorder dropping the power sample of an
+      advancing remote KV read;
+    * prefix_cow_deadlines: the sum seeded from the prompt length instead
+      of the admitted request's context;
+    * overlap_1_paged: the affine price, which serializes every C2C
+      cycle, used at overlap > 0;
+    * static_ccpg_paged: the affine price without the static-CCPG wake
+      residue;
+    * dynamic_ccpg_overlap_half_paged: the columnar recorder losing a
+      ClusterWake's cluster;
+    * chunk_64_prefix_sharing, llama3_8b_chunked_ccpg: the prefill memo
+      keyed without the cached context before the chunk.
+    """
+    cfg = get_config(arch)
+    fast, ref = _engine_pair(cfg, **engine_kw(cfg))
+    reqs = trace()
+    r_fast = fast.run([copy.copy(r) for r in reqs])
+    r_ref = ref.run([copy.copy(r) for r in reqs])
+    assert witness(r_fast, fast.kv_stats)
+    assert _hexdict(r_fast) == _hexdict(r_ref)
+    assert r_fast.queue_depth == r_ref.queue_depth
+    if fast.kv_stats is not None:
+        assert fast.kv_stats.row() == ref.kv_stats.row()
+    assert fast.events == ref.events
+    assert fast.timeline.events == ref.timeline.events
+
+
 # ---------------------------------------------------------------------------
 # Memoized CycleModel == direct walk
 # ---------------------------------------------------------------------------
@@ -296,6 +419,60 @@ def test_calibration_mutation_invalidates_memo(cfg):
     assert after == fresh.batched_token_decode_cycles(cfg, alloc, [512] * 4)
     assert p_after == fresh.prefill_cycles(cfg, alloc, 512)
     assert after != before and p_after != p_before
+
+
+def test_recalibration_between_runs_of_one_engine(cfg):
+    """One engine run twice on one simulator, with ``alpha`` and the
+    per-layer overhead changed on its cycle model between the runs: the
+    second run prices with the new constants, exactly as a fresh engine
+    built with them does.  Fails when a calibration change leaves a
+    decode or prefill memo entry in use."""
+    trace = poisson_trace(10, 40.0, seed=5, prompt_len=384, max_new=32)
+    sim = PicnicSimulator()
+    eng = ContinuousBatchingEngine(
+        cfg, sim=sim, engine=ServingConfig(max_batch=8,
+                                           chunked_prefill_tokens=128))
+    before = eng.run([copy.copy(r) for r in trace])
+    cm = sim.cycle_model
+    cm.alpha = cm.alpha * 0.5
+    cm.layer_fixed_cycles = cm.layer_fixed_cycles * 2.0
+    after = eng.run([copy.copy(r) for r in trace])
+    fresh_sim = PicnicSimulator()
+    fresh_sim.cycle_model.alpha = cm.alpha
+    fresh_sim.cycle_model.layer_fixed_cycles = cm.layer_fixed_cycles
+    fresh = ContinuousBatchingEngine(
+        cfg, sim=fresh_sim, engine=eng.engine).run(
+            [copy.copy(r) for r in trace])
+    assert after.wall_s != before.wall_s
+    assert _hexdict(after) == _hexdict(fresh)
+
+
+def test_engines_sharing_simulator_and_allocation_match_solo(cfg):
+    """Engines of two models on one simulator, two of them on one
+    allocation, run in turn: each reports what it reports alone on a
+    simulator of its own.  Fails when a decode or prefill memo key omits
+    the model or the allocation, so one model is priced at another's
+    costs."""
+    other = get_config("smollm-360m")
+    sim = PicnicSimulator()
+    alloc = allocate_chiplets(cfg, sim.tile)
+    trace = poisson_trace(10, 40.0, seed=6, prompt_len=256, max_new=24)
+    plans = [(cfg, alloc, dict(max_batch=2)),
+             (other, None, dict(max_batch=4, chunked_prefill_tokens=64)),
+             (cfg, alloc, dict(max_batch=4, chunked_prefill_tokens=64)),
+             (other, None, dict(max_batch=2))]
+    shared = [ContinuousBatchingEngine(c, sim=sim, alloc=a,
+                                       engine=ServingConfig(**kw))
+              for c, a, kw in plans]
+    for eng in shared:
+        assert eng.sim is sim
+    assert shared[0].alloc is shared[2].alloc
+    for (c, _, kw), eng in zip(plans, shared):
+        got = eng.run([copy.copy(r) for r in trace])
+        solo = ContinuousBatchingEngine(
+            c, sim=PicnicSimulator(), engine=ServingConfig(**kw)).run(
+                [copy.copy(r) for r in trace])
+        assert _hexdict(got) == _hexdict(solo)
 
 
 def test_nonaffine_subclass_falls_back_to_walk(cfg):

@@ -10,8 +10,9 @@ import pytest
 from repro.configs import get_config
 from repro.core import PicnicSimulator
 from repro.core.interconnect import MeasuredTraffic
-from repro.launch import (FleetConfig, ServingConfig, Trace, fleet,
-                          poisson_trace, replay_trace, serve, sweep)
+from repro.launch import (FleetConfig, ServingConfig, SweepCell, Trace,
+                          fleet, poisson_trace, replay_trace, serve, sweep)
+from repro.launch.serving_engine import ContinuousBatchingEngine
 from repro.runtime.kv_cache import KVCacheConfig
 
 
@@ -147,7 +148,6 @@ def cfg():
 
 
 def test_serve_facade_matches_engine_run(cfg):
-    from repro.launch.serving_engine import ContinuousBatchingEngine
     trace = Trace.poisson(8, rate_rps=40, seed=0, prompt_len=256,
                           max_new=8)
     sc = ServingConfig(max_batch=4, ccpg=True)
@@ -170,17 +170,18 @@ def test_fleet_facade_matches_engine_run(cfg):
     assert r1.row() == r2.row()
 
 
-def test_sweep_facade_matches_sweep_serve(cfg):
-    from repro.launch.sweep_engine import SweepCell, sweep_serve
-    def cells():
-        return [SweepCell(f"b{b}", cfg,
-                          Trace.poisson(6, rate_rps=40, seed=0,
-                                        prompt_len=256, max_new=8),
-                          ServingConfig(max_batch=b))
-                for b in (1, 4)]
-    r1 = sweep(cells())
-    r2 = sweep_serve(cells())
-    assert [r.report.row() for r in r1] == [r.report.row() for r in r2]
+def test_sweep_facade_matches_per_cell_engines(cfg):
+    trace = Trace.poisson(6, rate_rps=40, seed=0, prompt_len=256, max_new=8)
+    cells = [SweepCell(f"b{b}", cfg, trace, ServingConfig(max_batch=b))
+             for b in (1, 4)]
+    results = sweep(cells)
+    assert [r.key for r in results] == ["b1", "b4"]
+    for res, cell in zip(results, cells):
+        eng = ContinuousBatchingEngine(cfg, sim=PicnicSimulator(),
+                                       engine=cell.engine)
+        assert res.report.row() == eng.run(
+            [copy.copy(r) for r in trace]).row()
+        assert res.kv_stats is None
 
 
 def test_serving_report_row_attribution_fields(cfg):
