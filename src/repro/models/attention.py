@@ -6,7 +6,10 @@ Design notes (PICNIC adaptation, see DESIGN.md §3):
     score matrix is never materialized, and a block that the causal, window
     or length mask hides entirely is skipped, in the gradient too.  This
     mirrors the paper's FlashAttention two-level nested loop on the IPCN mesh.
-  * decode computes q against the full KV cache.  When the cache is
+  * decode computes q against the cached positions its length can reach: the
+    allocated length is cut into ``DECODE_SPANS`` equal spans and a step
+    reads only the spans below its own position (grouped K/V heads read it
+    as one span, ``_span_width``).  When the cache is
     sequence-sharded over the ``model`` mesh axis (the PICNIC
     distributed-scratchpad scheme) the softmax reduction becomes an
     in-network (ICI) reduction.  ``decode_attention_partial`` exposes the
@@ -32,6 +35,9 @@ NEG_INF = -1e30
 # blocks (36 of 64 computed) against 29.6 ms with 512 (10 of 16): the f32
 # score tile is a quarter the size and less of the triangle is computed.
 CHUNK = 256
+# Equal spans a decode step's cache read is cut into; a step reads the fewest
+# whole spans that hold its cached positions.
+DECODE_SPANS = 8
 
 
 # ---------------------------------------------------------------------------
@@ -450,21 +456,69 @@ def _cached_valid(kpos, cache_len, window, B):
     return jnp.broadcast_to(valid, (B, kpos.shape[0]))
 
 
-def decode_attention(q, k_cache, v_cache, k_new, v_new, cache_len, *,
+def _span_width(max_len: int, groups: int) -> int:
+    """Positions in each span of a max_len cache read by query heads in
+    ``groups`` per K/V head.  Grouped heads read the cache as one span:
+    their contraction lowers to a dot that wants the K/V head-major, so a
+    switch over the stack would have XLA copy all of it to that layout."""
+    return max_len if groups > 1 else -(-max_len // DECODE_SPANS)
+
+
+def _spans_read(cache_len, width: int, n_spans: int):
+    """Spans a decode step at ``cache_len`` reads: the fewest whose
+    ``width`` positions hold its cached positions 0..cache_len-2, at least
+    one, at most ``n_spans``.  Takes a Python int or a traced value, so the
+    same rule runs inside the step and in ``decode_span``."""
+    return jnp.clip(-(-(cache_len - 1) // width), 1, n_spans)
+
+
+def decode_span(max_len: int, cache_len: int, groups: int = 1):
+    """(positions read, positions allocated) of one ``decode_attention``
+    step at ``cache_len`` over a ``max_len`` cache, with ``groups`` query
+    heads per K/V head."""
+    width = _span_width(max_len, groups)
+    n = int(_spans_read(cache_len, width, -(-max_len // width)))
+    return min(n * width, max_len), max_len
+
+
+def decode_attention(q, k_cache, v_cache, layer, k_new, v_new, cache_len, *,
                      window=None):
-    """q: (B, 1, Hq, D) vs the cache (B, S, Hkv, D) before this step, whose
-    positions >= cache_len - 1 are masked, plus the new token's own K/V
-    (B, 1, Hkv, D) at position cache_len - 1, in one float32 softmax.
+    """q: (B, 1, Hq, D) vs layer ``layer`` of the stacked cache (L, B, S,
+    Hkv, D) before this step, whose positions >= cache_len - 1 are masked,
+    plus the new token's own K/V (B, 1, Hkv, D) at position cache_len - 1,
+    in one float32 softmax.
+
+    Only the spans below cache_len - 1 are read (``decode_span``): a
+    ``lax.switch`` on the traced length picks the branch whose static slice
+    holds them.  Each branch slices the layer out of the whole stack itself,
+    so that the slice fuses into its reads; a layer's slice handed to the
+    switch would be copied first.  Positions beyond the span are masked
+    anyway, so every valid term is as over the whole cache.
 
     Pure jnp: under jit+GSPMD a seq-sharded cache turns the reduction into
     ICI collectives automatically (baseline path).
     """
     B, _, Hq, D = q.shape
-    valid = _cached_valid(jnp.arange(k_cache.shape[1]), cache_len, window, B)
-    o, m, l = merge_partials(
-        decode_attention_partial(q[:, 0], k_cache, v_cache, valid),
-        token_partial(q[:, 0], k_new, v_new))
-    out = o / jnp.maximum(l[..., None], 1e-30)
+    max_len, Hkv = k_cache.shape[2], k_cache.shape[3]
+    width = _span_width(max_len, Hq // Hkv)
+    n_spans = -(-max_len // width)
+
+    def read(size):
+        def attend(k_cache, v_cache):
+            start = (layer, 0, 0, 0, 0)
+            shape = (1, B, size, Hkv, D)
+            k = jax.lax.dynamic_slice(k_cache, start, shape)[0]
+            v = jax.lax.dynamic_slice(v_cache, start, shape)[0]
+            valid = _cached_valid(jnp.arange(size), cache_len, window, B)
+            o, m, l = merge_partials(
+                decode_attention_partial(q[:, 0], k, v, valid),
+                token_partial(q[:, 0], k_new, v_new))
+            return o / jnp.maximum(l[..., None], 1e-30)
+        return attend
+
+    branches = [read(min(n * width, max_len)) for n in range(1, n_spans + 1)]
+    out = jax.lax.switch(_spans_read(cache_len, width, n_spans) - 1,
+                         branches, k_cache, v_cache)
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
 
@@ -506,12 +560,15 @@ def attn_sublayer(cfg, p, x, *, positions, causal=True, impl="flash",
     return out @ p["wo"], (k, v)
 
 
-def attn_decode_sublayer(cfg, p, x, cache_k, cache_v, cache_len, *,
+def attn_decode_sublayer(cfg, p, x, cache_k, cache_v, layer, cache_len, *,
                          window=None):
-    """One-token decode: x (B, 1, d) at position cache_len - 1.  The cache
-    is only read; the new token's K/V are attended as the cache will hold
-    them (rounded to its dtype) and returned for the caller to write at
-    cache_len - 1.  Returns (out (B, 1, d), k_new, v_new (B, 1, Hkv, D))."""
+    """One-token decode: x (B, 1, d) at position cache_len - 1, against
+    layer ``layer`` of the stacked cache (L, B, S, Hkv, D), which the step's
+    layer scan carries whole rather than slicing it per layer.  The cache
+    is only read, up to the spans its length reaches (``decode_attention``);
+    the new token's K/V are attended as the cache will hold them (rounded
+    to its dtype) and returned for the caller to write at cache_len - 1.
+    Returns (out (B, 1, d), k_new, v_new (B, 1, Hkv, D))."""
     q, k, v = qkv_project(cfg, p, x)
     pos = jnp.asarray(cache_len - 1)[None]
     if cfg.use_rope:
@@ -520,17 +577,18 @@ def attn_decode_sublayer(cfg, p, x, cache_k, cache_v, cache_len, *,
     k = k.astype(cache_k.dtype)
     v = v.astype(cache_v.dtype)
     B = x.shape[0]
-    seq_axes = _picnic_seq_axes(cache_k.shape[1])
+    seq_axes = _picnic_seq_axes(cache_k.shape[2])
     with jax.named_scope("attend"):
         if seq_axes:
             ctx = shctx.current()
             out = picnic_decode_attention(
-                q, k, v, cache_k, cache_v, cache_len, mesh=ctx.mesh,
+                q, k, v, cache_k[layer], cache_v[layer], cache_len,
+                mesh=ctx.mesh,
                 dp_axes=tuple(ctx.opt("dp_axes", ("data",))),
                 seq_axes=seq_axes, window=window)
         else:
-            out = decode_attention(q, cache_k, cache_v, k, v, cache_len,
-                                   window=window)
+            out = decode_attention(q, cache_k, cache_v, layer, k, v,
+                                   cache_len, window=window)
     out = out.reshape(B, 1, cfg.q_dim)
     return out @ p["wo"], k, v
 

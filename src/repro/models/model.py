@@ -294,24 +294,30 @@ def init_cache(cfg, batch: int, max_len: int):
     return cache
 
 
-def _block_decode(cfg, kind, p, x, c, cache_len):
-    """One block's decode over its cache entry ``c``, which it only reads.
-    Returns (x, new): an attention block's new token K/V (B, 1, Hkv, D),
-    to be written at cache_len - 1, or a Mamba block's new state."""
+def _block_decode(cfg, kind, p, x, c, layer, cache_len):
+    """One block's decode at group ``layer`` of its cache entry ``c``, whose
+    leaves are stacked over the groups.  Returns (x, c) with the entry
+    updated in place: an attention block's new token K/V written at
+    (layer, :, cache_len - 1) once the attention has read the stack, or a
+    Mamba block's new state at ``layer``."""
     if kind in ("dense", "moe", "dec", "shared_attn"):
         with jax.named_scope("attention"):
             h = apply_norm(cfg, p.get("ln1"), x)
             attn_out, k, v = A.attn_decode_sublayer(
-                cfg, p["attn"], h, c["k"], c["v"], cache_len,
+                cfg, p["attn"], h, c["k"], c["v"], layer, cache_len,
                 window=cfg.sliding_window)
             x = x + attn_out
+            with jax.named_scope("kv_write"):
+                c = dict(c, **{name: shard_hint(jax.lax.dynamic_update_slice(
+                    c[name], new[None], (layer, 0, cache_len - 1, 0, 0)),
+                    "kv_cache") for name, new in (("k", k), ("v", v))})
         if kind == "dec":
             with jax.named_scope("attention"):
                 h = apply_norm(cfg, p["lnx"], x)
                 q, _, _ = A.qkv_project(cfg, p["cross"], h)
                 with jax.named_scope("attend"):
-                    co = A.full_attention(q, c["cross_k"], c["cross_v"],
-                                          causal=False)
+                    co = A.full_attention(q, c["cross_k"][layer],
+                                          c["cross_v"][layer], causal=False)
                 x = x + co.reshape(x.shape[0], 1, cfg.q_dim) @ p["cross"]["wo"]
         with jax.named_scope("moe" if kind == "moe" else "mlp"):
             h = apply_norm(cfg, p["ln2"], x)
@@ -320,41 +326,29 @@ def _block_decode(cfg, kind, p, x, c, cache_len):
             else:
                 y = M.mlp_sublayer(cfg, p["mlp"], h)
             x = x + y
-        return x, {"k": k, "v": v}
+        return x, c
     if kind == "mamba":
         with jax.named_scope("mamba"):
             h = apply_norm(cfg, p["ln1"], x)
-            y, conv_s, ssm_s = S.mamba_decode_sublayer(cfg, p["mamba"], h,
-                                                       c["conv"], c["ssm"])
+            y, conv_s, ssm_s = S.mamba_decode_sublayer(
+                cfg, p["mamba"], h, c["conv"][layer], c["ssm"][layer])
             x = x + y
-        return x, {"conv": conv_s, "ssm": ssm_s}
+        return x, {name: jax.lax.dynamic_update_index_in_dim(c[name], new,
+                                                             layer, 0)
+                   for name, new in (("conv", conv_s), ("ssm", ssm_s))}
     raise ValueError(kind)
-
-
-def _write_token(c, new, cache_len):
-    """A block's cache entry after the step: an attention block's stacked
-    K/V (groups, B, max_len, Hkv, D) with the new token's (groups, B, 1, Hkv,
-    D) written at cache_len - 1, in place where the cache is donated (its
-    other leaves, whisper's cross K/V, as they were); a Mamba block's new
-    state."""
-    if "k" not in c:
-        return new
-    idx = cache_len - 1
-    out = dict(c)
-    for name in ("k", "v"):
-        out[name] = shard_hint(jax.lax.dynamic_update_slice(
-            c[name], new[name], (0, 0, idx, 0, 0)), "kv_cache")
-    return out
 
 
 def decode_step(cfg, params, token, cache, cache_len):
     """token: (B, 1) int32; cache_len: scalar (tokens valid AFTER this step).
     Returns (logits (B,1,V), new_cache).
 
-    The layer scan only reads the stacked cache and yields each attention
-    block's new K/V; one write per stack after the scan places them, so the
-    stack is not copied through the scan."""
-    kinds, _ = group_layout(cfg)
+    The layer scan carries the whole stacked cache and hands each block its
+    group's index: the attention reads, straight from the stack, the spans
+    of its group that the step's length reaches (``attention.
+    decode_attention``), then writes the new token's K/V into the stack in
+    place, so no layer's K/V is sliced out or copied through the scan."""
+    kinds, n_groups = group_layout(cfg)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], token, axis=0)
     if cfg.is_encoder_decoder:  # whisper: absolute sinusoidal positions
@@ -362,25 +356,20 @@ def decode_step(cfg, params, token, cache, cache_len):
         x = x + sinusoidal_at(cache_len - 1, cfg.d_model).astype(x.dtype)[None, None]
     shared = params.get("shared_attn")
 
-    def body(x, xs):
-        gp, gc = xs
-        new = {}
+    def body(carry, xs):
+        x, cache = carry
+        gp, layer = xs
+        cache = dict(cache)
         for i, kind in enumerate(kinds):
-            if kind == "shared_attn":
-                key = f"b{i}_shared"
-                x, new[key] = _block_decode(cfg, "shared_attn", shared, x,
-                                            gc[key], cache_len)
-            else:
-                key = f"b{i}_{kind}"
-                x, new[key] = _block_decode(cfg, kind, gp[key], x, gc[key],
-                                            cache_len)
-        return x, new
+            key = f"b{i}_shared" if kind == "shared_attn" else f"b{i}_{kind}"
+            p = shared if kind == "shared_attn" else gp[key]
+            x, cache[key] = _block_decode(cfg, kind, p, x, cache[key], layer,
+                                          cache_len)
+        return (x, cache), None
 
     with jax.named_scope("layers"):
-        x, new = jax.lax.scan(body, x, (params["layers"], cache))
-        with jax.named_scope("attention"), jax.named_scope("kv_write"):
-            cache = {key: _write_token(c, new[key], cache_len)
-                     for key, c in cache.items()}
+        (x, cache), _ = jax.lax.scan(body, (x, cache),
+                                     (params["layers"], jnp.arange(n_groups)))
     with jax.named_scope("head"):
         x = apply_norm(cfg, params["final_norm"], x)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

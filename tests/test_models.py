@@ -1,4 +1,5 @@
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro import models
 from repro.configs import ASSIGNED_ARCHS, get_smoke_config
-from repro.models.attention import (flash_attention, flash_blocks,
+from repro.models.attention import (decode_attention, decode_span,
+                                    flash_attention, flash_blocks,
                                     full_attention)
 
 KEY = jax.random.PRNGKey(0)
@@ -272,6 +274,92 @@ def test_flash_blocks_matches_the_mask(case):
     sq, skv, c, kw = MASK_CASES[case]
     assert flash_blocks(sq, skv, q_chunk=c, kv_chunk=c, **kw)[0] == \
         _needed_blocks(sq, skv, c, **kw)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over the spans its length reaches
+# ---------------------------------------------------------------------------
+
+DECODE_MAX_LEN = 64      # 8 spans of 8 positions
+
+
+def _decode_inputs(hq, hkv, layers=3, B=2, D=16):
+    ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 5)
+    shape = (layers, B, DECODE_MAX_LEN, hkv, D)
+    return (jax.random.normal(ks[0], (B, 1, hq, D)),
+            jax.random.normal(ks[1], shape), jax.random.normal(ks[2], shape),
+            jax.random.normal(ks[3], (B, 1, hkv, D)),
+            jax.random.normal(ks[4], (B, 1, hkv, D)))
+
+
+def _whole_cache_decode(q, k, v, k_new, v_new, cache_len, window):
+    """One softmax over every allocated position, those the step may not
+    attend masked out, and the new token's key."""
+    B, _, Hq, D = q.shape
+    S, Hkv = k.shape[1], k.shape[2]
+    kpos = np.arange(S)
+    valid = kpos < cache_len - 1
+    if window is not None:
+        valid &= kpos >= cache_len - window
+    keys = jnp.repeat(jnp.concatenate([k, k_new], 1), Hq // Hkv, axis=2)
+    vals = jnp.repeat(jnp.concatenate([v, v_new], 1), Hq // Hkv, axis=2)
+    s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], keys) * D ** -0.5
+    s = jnp.where(np.append(valid, True), s, -jnp.inf)
+    out = jnp.einsum("bhk,bkhd->bhd", jax.nn.softmax(s, axis=-1), vals)
+    return out[:, None]
+
+
+@pytest.mark.parametrize("window", [None, 5], ids=["no-window", "window"])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2)], ids=["mha", "gqa-4to1"])
+@pytest.mark.parametrize("cache_len", [8, 9, 10, DECODE_MAX_LEN])
+def test_decode_attention_equals_the_whole_cache_softmax(cache_len, hq, hkv,
+                                                         window):
+    """On each side of a span edge (width 8) and at the full cache, reading
+    only the spans the length reaches gives the whole cache's softmax, for
+    the stack's layer the index names."""
+    q, k, v, k_new, v_new = _decode_inputs(hq, hkv)
+    layer = 1
+    got = jax.jit(functools.partial(decode_attention, window=window))(
+        q, k, v, jnp.int32(layer), k_new, v_new, jnp.int32(cache_len))
+    want = _whole_cache_decode(q, k[layer], v[layer], k_new, v_new,
+                               cache_len, window)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 5], ids=["no-window", "window"])
+@pytest.mark.parametrize("cache_len", [2, 9, 17, 40])
+def test_decode_attention_never_reads_beyond_its_spans(cache_len, window):
+    """NaN in every position past the spans a step reads leaves its output
+    finite and as it was; a read of the whole cache would turn it NaN."""
+    q, k, v, k_new, v_new = _decode_inputs(4, 4)
+    read, _ = decode_span(DECODE_MAX_LEN, cache_len)
+    poison = lambda x: x.at[:, :, read:].set(jnp.nan)
+    args = (jnp.int32(0), k_new, v_new, jnp.int32(cache_len))
+    step = jax.jit(functools.partial(decode_attention, window=window))
+    got = step(q, poison(k), poison(v), *args)
+    assert read < DECODE_MAX_LEN and bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(got, step(q, k, v, *args))
+
+
+@pytest.mark.parametrize("max_len", [5, 28, 64, 256, 1024, 2048])
+def test_decode_span_covers_the_step_and_stays_in_the_cache(max_len):
+    width = -(-max_len // 8)
+    for cache_len in range(1, max_len + 1):
+        read, allocated = decode_span(max_len, cache_len)
+        assert allocated == max_len
+        assert cache_len - 1 <= read <= max_len
+        assert read == max_len or read % width == 0
+        assert read < max(cache_len - 1, 1) + width
+        assert decode_span(max_len, cache_len, groups=4) == (max_len, max_len)
+
+
+def test_decode_span_over_the_decode_cells_wave():
+    """Prompt 128, output 128, max_len 256: the wave's 127 decode steps run
+    at cache_len 129..255 and read 206.6 of 256 positions on average."""
+    reads = [decode_span(256, n)[0] for n in range(129, 256)]
+    assert sum(reads) == 26240 and round(sum(reads) / len(reads), 1) == 206.6
+    # the prefill cell's 15 steps at 2048 read the whole cache
+    assert {decode_span(2048, n) for n in range(1922, 1936)} == {(2048, 2048)}
 
 
 def test_attention_is_permutation_equivariant_over_batch():
